@@ -33,6 +33,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     quick = args.quick or args.smoke
 
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (compile_backends, emit_footprint, fig3_4_time,
                    fig5_6_memory, fig7_8_modifications, kernels_bench,
                    lm_quantized, megakernel, quant_accuracy, roofline_table,

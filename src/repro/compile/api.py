@@ -244,7 +244,6 @@ def specialize_mesh(artifact: CompiledArtifact, mesh: Any,
             raise TypeError(
                 f"'{artifact.kind}' program is not jittable; spmd mesh "
                 f"specialization needs a traceable predict")
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         axes = shrules.batch_axes(mesh)
@@ -257,8 +256,10 @@ def specialize_mesh(artifact: CompiledArtifact, mesh: Any,
                     lambda s: jax.lax.psum(s, axes), stats)
             return out, stats
 
-        inner = jax.jit(shard_map(_shard_fn, mesh=mesh, in_specs=(spec,),
-                                  out_specs=(spec, P()), check_rep=False))
+        inner = jax.jit(jax.shard_map(_shard_fn, mesh=mesh,
+                                      in_specs=(spec,),
+                                      out_specs=(spec, P()),
+                                      check_vma=False))
     else:
         inner = artifact._predict  # already specialized (jit + batch policy)
 

@@ -12,7 +12,7 @@ from repro.core import fixedpoint as fxp
 from repro.core.fixedpoint import STATS_DTYPE, FxpFormat, FxpStats
 
 __all__ = ["zero_stats", "q", "qx_with_stats", "nbytes", "elem_bytes",
-           "resolve_formats"]
+           "resolve_formats", "check_pallas_container"]
 
 
 def zero_stats() -> FxpStats:
@@ -63,3 +63,19 @@ def resolve_formats(target, plan) -> Optional[Callable[[str], FxpFormat]]:
     if fixed is None:
         return None
     return lambda path: fixed
+
+
+def check_pallas_container(target, fmt: FxpFormat) -> None:
+    """Refuse a 32-bit container on the ``pallas`` backend on a TPU.
+
+    Its kernels need int64 intermediates, which Mosaic cannot hold, so no
+    exact chip program exists; the Target fails at compile time instead of
+    running somewhere else.  Interpret mode (off the TPU) runs it exactly.
+    """
+    from repro.kernels import tune
+
+    if fmt.total_bits == 32 and tune.on_tpu():
+        raise NotImplementedError(
+            f"{target!r} cannot run on a TPU: 32-bit containers need int64 "
+            f"intermediates that the Pallas TPU compiler cannot hold; use "
+            f"backend='xla' or a 16/8-bit number format")

@@ -29,8 +29,8 @@ from repro.quant import Calibration, amax
 
 from ..registry import Lowered, Lowering, register_lowering
 from ..target import Target
-from .common import (elem_bytes, nbytes, q, qx_with_stats, resolve_formats,
-                     zero_stats)
+from .common import (check_pallas_container, elem_bytes, nbytes, q,
+                     qx_with_stats, resolve_formats, zero_stats)
 
 
 def calibrate_linear(coef: np.ndarray, intercept: np.ndarray,
@@ -71,6 +71,8 @@ def lower_linear(coef: np.ndarray, intercept: np.ndarray, target: Target,
 
         if target.backend == "pallas":
             from repro.kernels import ops
+
+            check_pallas_container(target, in_fmt)
 
             def predict(x):
                 qx, stats = qx_with_stats(jnp.asarray(x, jnp.float32), in_fmt)

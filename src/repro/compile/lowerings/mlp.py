@@ -42,8 +42,8 @@ from repro.quant import Calibration, activation_range, amax
 
 from ..registry import Lowered, Lowering, register_lowering
 from ..target import Target
-from .common import (elem_bytes, nbytes, q, qx_with_stats, resolve_formats,
-                     zero_stats)
+from .common import (check_pallas_container, elem_bytes, nbytes, q,
+                     qx_with_stats, resolve_formats, zero_stats)
 
 
 @register_lowering("mlp")
@@ -127,6 +127,7 @@ class MLPLowering(Lowering):
             if target.backend == "pallas":
                 from repro.kernels import fxp_model, ops
 
+                check_pallas_container(target, in_fmt)
                 # The whole forward as ONE dispatch when the packed weights
                 # fit the VMEM budget (always, for paper-scale models);
                 # otherwise the PR-3 per-layer fused path — bit-identical

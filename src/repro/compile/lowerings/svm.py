@@ -36,8 +36,8 @@ from repro.quant import Calibration, amax
 
 from ..registry import Lowered, Lowering, register_lowering
 from ..target import Target
-from .common import (elem_bytes, nbytes, q, qx_with_stats, resolve_formats,
-                     zero_stats)
+from .common import (check_pallas_container, elem_bytes, nbytes, q,
+                     qx_with_stats, resolve_formats, zero_stats)
 from .linear import calibrate_linear, lower_linear
 
 
@@ -177,6 +177,7 @@ def _lower_kernel_svm(p: Dict[str, Any], target: Target,
         if target.backend == "pallas":
             from repro.kernels import fxp_model, ops
 
+            check_pallas_container(target, fmt)
             extras["kernel_strategy"] = "per-layer"
 
             def matmul(a, b):

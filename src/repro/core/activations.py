@@ -120,7 +120,9 @@ def qsigmoid_pwl2(x: jax.Array, fmt: FxpFormat) -> jax.Array:
     one = one_q(fmt)
     half = int(fmt.scale) >> 1
     ramp = _rshift_round(x.astype(fmt.wide_dtype), 2) + half
-    return _saturate(jnp.clip(ramp, 0, one), fmt)
+    ramp = jnp.minimum(jnp.maximum(ramp, jnp.zeros_like(ramp)),
+                       jnp.asarray(one, ramp.dtype))
+    return _saturate(ramp, fmt)
 
 
 def pwl4_consts(fmt: FxpFormat) -> Dict[str, int]:

@@ -76,12 +76,18 @@ class FxpFormat:
     total_bits: int
     frac_bits: int
     name: str = ""
+    # Width of the wide intermediates; 0 = twice the container (the MCU's
+    # wide type).  Kernel bodies widen 8-bit containers to 32 (see
+    # repro.kernels.mosaic.vpu_format).
+    wide_bits: int = 0
 
     def __post_init__(self):
         if self.total_bits not in (8, 16, 32):
             raise ValueError(f"unsupported container width {self.total_bits}")
         if not 0 <= self.frac_bits < self.total_bits:
             raise ValueError(f"frac_bits {self.frac_bits} out of range")
+        if self.wide_bits not in (0, 16, 32, 64):
+            raise ValueError(f"unsupported wide width {self.wide_bits}")
 
     # --- static properties -------------------------------------------------
     @property
@@ -119,7 +125,8 @@ class FxpFormat:
     @property
     def wide_dtype(self) -> jnp.dtype:
         """Accumulator dtype wide enough to hold a product of two values."""
-        return {8: jnp.int16, 16: jnp.int32, 32: jnp.int64}[self.total_bits]
+        bits = self.wide_bits or 2 * self.total_bits
+        return {16: jnp.int16, 32: jnp.int32, 64: jnp.int64}[bits]
 
     def __repr__(self) -> str:  # pragma: no cover - cosmetic
         return self.name or f"Q{self.int_bits}.{self.frac_bits}/{self.total_bits}b"
@@ -185,7 +192,11 @@ jax.tree_util.register_pytree_node(
 
 
 def _saturate(x_wide: jax.Array, fmt: FxpFormat) -> jax.Array:
-    return jnp.clip(x_wide, fmt.qmin, fmt.qmax).astype(fmt.dtype)
+    # Bounds typed to the operand: Python-int bounds are int64 under x64,
+    # which Mosaic cannot lower inside a kernel body.
+    lo = jnp.asarray(fmt.qmin, x_wide.dtype)
+    hi = jnp.asarray(fmt.qmax, x_wide.dtype)
+    return jnp.minimum(jnp.maximum(x_wide, lo), hi).astype(fmt.dtype)
 
 
 def one_q(fmt: FxpFormat) -> int:
@@ -310,17 +321,23 @@ def qmul(a: jax.Array, b: jax.Array, fmt: FxpFormat) -> jax.Array:
 
 def qdiv(a: jax.Array, b: jax.Array, fmt: FxpFormat) -> jax.Array:
     """(a << m) / b with round-to-nearest, saturating. b == 0 saturates."""
-    wide_a = a.astype(fmt.wide_dtype) << fmt.frac_bits
-    wide_b = b.astype(fmt.wide_dtype)
-    safe_b = jnp.where(wide_b == 0, 1, wide_b)
-    sign = jnp.where((wide_a < 0) != (safe_b < 0), -1, 1).astype(fmt.wide_dtype)
+    wide = fmt.wide_dtype
+    wide_a = a.astype(wide) << fmt.frac_bits
+    wide_b = b.astype(wide)
+    # Every constant typed to the wide dtype: a Python scalar in jnp.where
+    # is int64 under x64, which no kernel body can hold.
+    one = jnp.ones_like(wide_b)
+    safe_b = jnp.where(wide_b == 0, one, wide_b)
+    sign = jnp.where((wide_a < 0) != (safe_b < 0), -one, one)
     # C-style truncating division on magnitudes, then round-to-nearest
     # (ties away from zero) — matches the MCU fixed-point division macro.
     q_trunc = sign * (jnp.abs(wide_a) // jnp.abs(safe_b))
     rem_t = wide_a - q_trunc * safe_b
-    adjust_t = (jnp.abs(rem_t) * 2 >= jnp.abs(safe_b)).astype(fmt.wide_dtype)
+    adjust_t = (jnp.abs(rem_t) * 2 >= jnp.abs(safe_b)).astype(wide)
     q_rounded = q_trunc + adjust_t * sign
-    out = jnp.where(wide_b == 0, jnp.where(a >= 0, fmt.qmax, fmt.qmin), q_rounded)
+    overflow = jnp.where(wide_a >= 0, jnp.asarray(fmt.qmax, wide),
+                         jnp.asarray(fmt.qmin, wide))
+    out = jnp.where(wide_b == 0, overflow, q_rounded)
     return _saturate(out, fmt)
 
 
@@ -404,9 +421,11 @@ def qexp(x: jax.Array, fmt: FxpFormat) -> jax.Array:
     # Scale by 2^k: left shift when k>=0 (with saturation), right when k<0.
     k_i32 = k.astype(jnp.int32)
     max_shift = fmt.total_bits  # beyond this always saturates / flushes
-    k_clamped = jnp.clip(k_i32, -max_shift, max_shift)
-    pos = jnp.where(k_clamped > 0, k_clamped, 0).astype(wide)
-    neg = jnp.where(k_clamped < 0, -k_clamped, 0).astype(wide)
+    k_clamped = jnp.minimum(jnp.maximum(k_i32, jnp.int32(-max_shift)),
+                            jnp.int32(max_shift))
+    zero = jnp.zeros_like(k_clamped)
+    pos = jnp.maximum(k_clamped, zero).astype(wide)
+    neg = jnp.maximum(-k_clamped, zero).astype(wide)
     shifted_up = acc << jnp.minimum(pos, fmt.total_bits - 1).astype(wide)
     # Detect overflow of the left shift on the wide dtype.
     overflowed = (shifted_up >> jnp.minimum(pos, fmt.total_bits - 1).astype(wide)) != acc
@@ -436,7 +455,7 @@ def qsigmoid(x: jax.Array, fmt: FxpFormat) -> jax.Array:
     denom = qadd(jnp.broadcast_to(one, e.shape), e, fmt)
     pos = qdiv(jnp.broadcast_to(one, e.shape), denom, fmt)  # sigmoid(|x|)
     neg = qsub(jnp.broadcast_to(one, e.shape), pos, fmt)
-    return jnp.where(x >= 0, pos, neg)
+    return jnp.where(x.astype(fmt.wide_dtype) >= 0, pos, neg)
 
 
 def qtanh(x: jax.Array, fmt: FxpFormat) -> jax.Array:

@@ -40,6 +40,8 @@ from repro.core import fixedpoint
 from repro.core.activations import get_qsigmoid
 from repro.core.fixedpoint import FxpFormat
 
+from .mosaic import index_map, mxu_dot, vpu_format
+
 __all__ = ["fxp_layer_pallas", "LAYER_ACTIVATIONS"]
 
 # "none" = linear output layer (logits); the rest are Qn.m sigmoid variants.
@@ -54,10 +56,7 @@ def _kernel(a_ref, b_ref, bias_ref, o_ref, acc_ref, *, fmt: FxpFormat,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    acc_ref[...] += mxu_dot(a_ref[...], b_ref[...], (((1,), (0,)), ((), ())))
 
     @pl.when(k == k_steps - 1)
     def _epilogue():
@@ -66,10 +65,11 @@ def _kernel(a_ref, b_ref, bias_ref, o_ref, acc_ref, *, fmt: FxpFormat,
         # path cannot drift from the chained semantics.  ``shift`` carries
         # mixed-format operands (per-tensor QuantPlan) into the output
         # format; for single-format layers it equals ``fmt.frac_bits``.
-        h = fixedpoint.requantize(acc_ref[...], shift, fmt)
-        h = fixedpoint.qadd(h, bias_ref[...][None, :], fmt)
+        vfmt = vpu_format(fmt)
+        h = fixedpoint.requantize(acc_ref[...], shift, vfmt)
+        h = fixedpoint.qadd(h, bias_ref[...], vfmt)
         if activation != "none":
-            h = get_qsigmoid(activation)(h, fmt)
+            h = get_qsigmoid(activation)(h, vfmt)
         o_ref[...] = h.astype(fmt.dtype)
 
 
@@ -106,10 +106,10 @@ def fxp_layer_pallas(a: jax.Array, b: jax.Array, bias: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), index_map(lambda i, j, kk: (0, j))),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), fmt.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(a, b, bias)
+    )(a, b, bias.reshape(1, n))

@@ -66,6 +66,8 @@ from repro.core.activations import get_qsigmoid
 from repro.core.fixedpoint import FxpFormat
 
 from .fxp_layer import LAYER_ACTIVATIONS
+from .mosaic import index_map, mxu_dot, vpu_format
+from . import tune
 from .tune import _VMEM_BUDGET
 
 __all__ = ["fxp_mlp_model_pallas", "fxp_svm_model_pallas", "LayerSchedule",
@@ -108,39 +110,54 @@ def vmem_budget() -> int:
 
 def _padded_dim(d: int) -> int:
     """Feature-dim size as the kernel sees it (lane-tiled on real TPU)."""
-    if jax.default_backend() == "tpu":
+    if tune.on_tpu():
         return -(-int(d) // _LANE) * _LANE
     return int(d)
+
+
+def _limb_bytes(bits: int) -> int:
+    """Kernel temporaries per element of a weight operand: a 16-bit dot
+    splits it in the kernel (an int32 copy and three int8 limbs, see
+    ``mosaic.mxu_dot``); an 8-bit one feeds the MXU as it is."""
+    return 7 if int(bits) == 16 else 0
 
 
 def mlp_vmem_bytes(widths: Sequence[int], bits: int, bm: int = 128) -> int:
     """Worst-case resident bytes of one MLP megakernel grid step.
 
     ``widths`` = [n_features, hidden..., n_classes] (logical; padded to the
-    TPU tile when relevant).  Counts every layer's packed weight + bias, the
-    batch block of inputs/outputs, and three ``bm x max_width`` int32
-    intermediates (accumulator + the epilogue's widened temporaries).
+    TPU tile when relevant).  Counts every layer's packed weight + bias and
+    the batch block of inputs/outputs, each double-buffered by the
+    pipeline; the weights' limb temporaries; and three ``bm x max_width``
+    int32 intermediates (accumulator + the epilogue's widened
+    temporaries).
     """
     dims = [_padded_dim(d) for d in widths]
     e = max(1, int(bits) // 8)
-    weights = sum(i * o for i, o in zip(dims, dims[1:])) * e
-    biases = sum(dims[1:]) * e
-    io = bm * (dims[0] + dims[-1]) * e
+    weights = sum(i * o for i, o in zip(dims, dims[1:]))
+    biases = sum(dims[1:])
+    io = bm * (dims[0] + dims[-1])
     scratch = 3 * bm * max(dims) * 4
-    return weights + biases + io + scratch
+    return (2 * (weights + biases + io) * e + weights * _limb_bytes(bits)
+            + scratch)
 
 
 def svm_vmem_bytes(n_sv: int, n_feat: int, n_classes: int, bits: int,
                    bm: int = 128) -> int:
-    """Worst-case resident bytes of one SVM megakernel grid step."""
+    """Worst-case resident bytes of one SVM megakernel grid step: the
+    double-buffered operands, the limb temporaries of both dots' weights,
+    the rbf norm's int32 squares of the support vectors (two more copies
+    for the 16-bit halves), and the (bm, n_sv) int32 intermediates."""
     s, f, c = (_padded_dim(d) for d in (n_sv, n_feat, n_classes))
     e = max(1, int(bits) // 8)
-    weights = (s * f + s * c + c) * e
-    io = bm * (f + c) * e
+    weights = s * f + s * c + c
+    io = bm * (f + c)
+    squares = s * f * (12 if int(bits) == 16 else 4)
     # The (bm, n_sv) kernel-value matrix dominates the intermediates: the
     # int32 dot accumulator plus the widened elementwise chain.
     scratch = 3 * bm * max(s, f, c) * 4
-    return weights + io + scratch
+    return (2 * (weights + io) * e + (s * f + s * c) * _limb_bytes(bits)
+            + squares + scratch)
 
 
 def mlp_fits_vmem(widths: Sequence[int], bits: int, bm: int = 128) -> bool:
@@ -193,17 +210,11 @@ def _mlp_kernel(*refs, schedule: LayerSchedule):
     h = x_ref[...]
     for (shift, fmt, activation), w_ref, b_ref in zip(
             schedule, wb[0::2], wb[1::2]):
-        acc = jax.lax.dot_general(
-            h.astype(jnp.int32), w_ref[...].astype(jnp.int32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
         # Same shared epilogue definitions as fxp_layer._kernel: the
         # megakernel cannot drift from the per-layer fused (or chained)
         # semantics because all three trace the same functions.
-        h = fixedpoint.requantize(acc, shift, fmt)
-        h = fixedpoint.qadd(h, b_ref[...][None, :], fmt)
-        if activation != "none":
-            h = get_qsigmoid(activation)(h, fmt)
-        h = h.astype(fmt.dtype)
+        h = _mlp_layer_step(h, w_ref[...], b_ref[...], shift, fmt,
+                            activation, batched=False)
     o_ref[...] = h
 
 
@@ -230,16 +241,19 @@ def fxp_mlp_model_pallas(x: jax.Array, weights: Tuple[jax.Array, ...],
     out_fmt = schedule[-1][1]
     n_out = weights[-1].shape[1]
 
-    in_specs = [pl.BlockSpec((bm, k0), lambda i: (i, 0))]
+    # Biases ride as (1, N) rows: a rank-1 block is not a Mosaic tile.
+    biases = tuple(b.reshape(1, -1) for b in biases)
+    whole = index_map(lambda i: (0, 0))
+    in_specs = [pl.BlockSpec((bm, k0), index_map(lambda i: (i, 0)))]
     for w, b in zip(weights, biases):
-        in_specs.append(pl.BlockSpec(w.shape, lambda i: (0, 0)))
-        in_specs.append(pl.BlockSpec(b.shape, lambda i: (0,)))
+        in_specs.append(pl.BlockSpec(w.shape, whole))
+        in_specs.append(pl.BlockSpec(b.shape, whole))
 
     return pl.pallas_call(
         functools.partial(_mlp_kernel, schedule=schedule),
         grid=(m // bm,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, n_out), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, n_out), index_map(lambda i: (i, 0))),
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_fmt.dtype),
         interpret=interpret,
     )(x, *chain.from_iterable(zip(weights, biases)))
@@ -248,57 +262,83 @@ def fxp_mlp_model_pallas(x: jax.Array, weights: Tuple[jax.Array, ...],
 # --------------------------------------------------------------------------
 # kernel-SVM megakernel (kernel evaluation + vote, one dispatch)
 # --------------------------------------------------------------------------
-def _svm_forward(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
-                 out_fmt: FxpFormat, qgamma: int, qcoef0: int, degree: int,
-                 dec_shift: int):
-    """The whole decision function on 2-D values (bm, F) -> (bm, C).
+def _qsq_norm(qv, fmt: FxpFormat, vfmt: FxpFormat):
+    """Row sums of squares, one rounded shift: the rbf norm terms, as a
+    (..., 1) column.
 
-    Shared between the single-model kernel body and the fleet kernel's
-    per-model branches — one spelling of the algebra, one bit-identity
-    contract.
+    The reference sums exactly (int64).  int32 holds that sum exactly for
+    8-bit values (K < 2^17 terms of at most 2^14).  16-bit squares reach
+    2^30, so they are summed as 16-bit halves: while the high half's sum
+    stays below 2^14 the recombined total fits int32 exactly, and past it
+    the total is at least 2^30, which saturates every 16-bit format.
     """
+    w = qv.astype(jnp.int32)
+    sq = w * w
+    if fmt.total_bits == 8:
+        acc = jnp.sum(sq, axis=-1, keepdims=True, dtype=jnp.int32)
+    else:
+        assert qv.shape[-1] < (1 << 14), qv.shape
+        hi = jnp.sum(sq >> jnp.int32(16), axis=-1, keepdims=True,
+                     dtype=jnp.int32)
+        lo = jnp.sum(sq & jnp.int32(0xFFFF), axis=-1, keepdims=True,
+                     dtype=jnp.int32)
+        acc = jnp.where(hi >= jnp.int32(1 << 14),
+                        jnp.full_like(hi, jnp.iinfo(jnp.int32).max),
+                        (hi << jnp.int32(16)) + lo)
+    return fixedpoint.rshift_round_saturate(acc, vfmt)
+
+
+def _svm_decision(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
+                  out_fmt: FxpFormat, qgamma: int, qcoef0: int, degree: int,
+                  dec_shift: int, batched: bool):
+    """The whole decision function on (bm, F) values -> (bm, C), or on
+    model-stacked (be, bm, F) -> (be, bm, C) when ``batched`` (the model
+    axis rides as a dot_general batch dimension; models never mix).
+    ``icept`` is (1, C) — or (be, 1, C) — so it broadcasts over rows.
+
+    One spelling of the algebra for the single-model kernel, the uniform
+    fleet and the fleet's per-model branches — one bit-identity contract.
+    """
+    vfmt, vout = vpu_format(fmt), vpu_format(out_fmt)
+    if batched:
+        sv_dims = (((2,), (2,)), ((0,), (0,)))
+        dual_dims = (((2,), (1,)), ((0,), (0,)))
+    else:
+        sv_dims = (((1,), (1,)), ((), ()))
+        dual_dims = (((1,), (0,)), ((), ()))
     # x . sv^T without materializing the transpose: contract the shared
     # feature axis.  Integer dot == fxp_qmatmul's accumulate, then the
     # single-format requantize (input/sv/kernel share one plan group).
-    dot = jax.lax.dot_general(
-        qx.astype(jnp.int32), qsv.astype(jnp.int32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
-    dot = fixedpoint.requantize(dot, fmt.frac_bits, fmt)
+    dot = mxu_dot(qx, qsv, sv_dims)
+    dot = fixedpoint.requantize(dot, fmt.frac_bits, vfmt)
     g = jnp.asarray(qgamma, fmt.dtype)
     if kind == "poly":
-        k = fixedpoint.qadd(fixedpoint.qmul(dot, g, fmt),
-                            jnp.asarray(qcoef0, fmt.dtype), fmt)
-        k = fixedpoint.qpow_int(k, degree, fmt)
+        k = fixedpoint.qadd(fixedpoint.qmul(dot, g, vfmt),
+                            jnp.asarray(qcoef0, fmt.dtype), vfmt)
+        k = fixedpoint.qpow_int(k, degree, vfmt)
     else:  # rbf
-        def _qsq_norm(qv):
-            wide = qv.astype(fmt.wide_dtype)
-            acc = jnp.sum(wide * wide, axis=-1)
-            return fixedpoint.rshift_round_saturate(acc, fmt)
-
-        x2 = _qsq_norm(qx)
-        sv2 = _qsq_norm(qsv)
+        x2 = _qsq_norm(qx, fmt, vfmt)  # (..., bm, 1) column
+        sv2 = jnp.swapaxes(_qsq_norm(qsv, fmt, vfmt), -1, -2)  # (..., 1, S)
         d2 = fixedpoint.qadd(
-            fixedpoint.qsub(x2[:, None], fixedpoint.qadd(dot, dot, fmt), fmt),
-            sv2[None, :], fmt)
-        arg = fixedpoint.qneg(fixedpoint.qmul(d2, g, fmt), fmt)
-        k = fixedpoint.qexp(arg, fmt)
+            fixedpoint.qsub(x2, fixedpoint.qadd(dot, dot, vfmt), vfmt),
+            sv2, vfmt)
+        arg = fixedpoint.qneg(fixedpoint.qmul(d2, g, vfmt), vfmt)
+        k = fixedpoint.qexp(arg, vfmt)
     # Decision stage: the fused-layer epilogue (k @ dual, cross-format
     # shift, saturating intercept add) still inside the same kernel body.
-    acc = jax.lax.dot_general(
-        k.astype(jnp.int32), dual.astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    out = fixedpoint.requantize(acc, dec_shift, out_fmt)
-    out = fixedpoint.qadd(out, icept[None, :], out_fmt)
+    acc = mxu_dot(k, dual, dual_dims)
+    out = fixedpoint.requantize(acc, dec_shift, vout)
+    out = fixedpoint.qadd(out, icept, vout)
     return out.astype(out_fmt.dtype)
 
 
 def _svm_kernel(x_ref, sv_ref, dual_ref, icept_ref, o_ref, *, kind: str,
                 fmt: FxpFormat, out_fmt: FxpFormat, qgamma: int, qcoef0: int,
                 degree: int, dec_shift: int):
-    o_ref[...] = _svm_forward(
+    o_ref[...] = _svm_decision(
         x_ref[...], sv_ref[...], dual_ref[...], icept_ref[...], kind=kind,
         fmt=fmt, out_fmt=out_fmt, qgamma=qgamma, qcoef0=qcoef0,
-        degree=degree, dec_shift=dec_shift)
+        degree=degree, dec_shift=dec_shift, batched=False)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -328,19 +368,21 @@ def fxp_svm_model_pallas(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     kernel = functools.partial(
         _svm_kernel, kind=kind, fmt=fmt, out_fmt=out_fmt, qgamma=qgamma,
         qcoef0=qcoef0, degree=int(degree), dec_shift=int(dec_shift))
+    rows = index_map(lambda i: (i, 0))
+    whole = index_map(lambda i: (0, 0))
     return pl.pallas_call(
         kernel,
         grid=(m // bm,),
         in_specs=[
-            pl.BlockSpec((bm, f), lambda i: (i, 0)),
-            pl.BlockSpec((s, f), lambda i: (0, 0)),
-            pl.BlockSpec((s, c), lambda i: (0, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
+            pl.BlockSpec((bm, f), rows),
+            pl.BlockSpec((s, f), whole),
+            pl.BlockSpec((s, c), whole),
+            pl.BlockSpec((1, c), whole),
         ],
-        out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, c), rows),
         out_shape=jax.ShapeDtypeStruct((m, c), out_fmt.dtype),
         interpret=interpret,
-    )(qx, sv, dual, icept)
+    )(qx, sv, dual, icept.reshape(1, c))
 
 
 # --------------------------------------------------------------------------
@@ -391,19 +433,18 @@ def _branch_index(indices) -> "jnp.ndarray":
 def _mlp_layer_step(h, w, b, shift: int, fmt: FxpFormat, activation: str,
                     batched: bool):
     """One fused layer on (bm, K) values — or (be, bm, K) when ``batched``,
-    contracting K with the model axis as a dot_general batch dim."""
+    contracting K with the model axis as a dot_general batch dim.  ``b`` is
+    the (1, N) — or (be, 1, N) — bias row."""
     if batched:
         dims = (((2,), (1,)), ((0,), (0,)))
-        bias = b[:, None, :]
     else:
         dims = (((1,), (0,)), ((), ()))
-        bias = b[None, :]
-    acc = jax.lax.dot_general(h.astype(jnp.int32), w.astype(jnp.int32),
-                              dims, preferred_element_type=jnp.int32)
-    h = fixedpoint.requantize(acc, shift, fmt)
-    h = fixedpoint.qadd(h, bias, fmt)
+    vfmt = vpu_format(fmt)
+    acc = mxu_dot(h, w, dims)
+    h = fixedpoint.requantize(acc, shift, vfmt)
+    h = fixedpoint.qadd(h, b, vfmt)
     if activation != "none":
-        h = get_qsigmoid(activation)(h, fmt)
+        h = get_qsigmoid(activation)(h, vfmt)
     return h.astype(fmt.dtype)
 
 
@@ -477,58 +518,24 @@ def fxp_mlp_fleet_pallas(x: jax.Array, weights: Tuple[jax.Array, ...],
     out_fmt = schedules[0][-1][1]
     n_out = weights[-1].shape[2]
 
-    in_specs = [pl.BlockSpec((be, bm, k0), lambda ei, mi: (ei, mi, 0))]
+    # Biases ride as (E, 1, N): the trailing (1, N) block equals the whole
+    # trailing dims, which Mosaic tiles for any model block ``be``.
+    biases = tuple(b.reshape(e, 1, -1) for b in biases)
+    rows = index_map(lambda ei, mi: (ei, mi, 0))
+    member = index_map(lambda ei, mi: (ei, 0, 0))
+    in_specs = [pl.BlockSpec((be, bm, k0), rows)]
     for w, b in zip(weights, biases):
-        in_specs.append(
-            pl.BlockSpec((be,) + w.shape[1:], lambda ei, mi: (ei, 0, 0)))
-        in_specs.append(
-            pl.BlockSpec((be,) + b.shape[1:], lambda ei, mi: (ei, 0)))
+        in_specs.append(pl.BlockSpec((be,) + w.shape[1:], member))
+        in_specs.append(pl.BlockSpec((be,) + b.shape[1:], member))
 
     return pl.pallas_call(
         functools.partial(_mlp_fleet_kernel, schedules=schedules, be=be),
         grid=(e // be, m // bm),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((be, bm, n_out), lambda ei, mi: (ei, mi, 0)),
+        out_specs=pl.BlockSpec((be, bm, n_out), rows),
         out_shape=jax.ShapeDtypeStruct((e, m, n_out), out_fmt.dtype),
         interpret=interpret,
     )(x, *chain.from_iterable(zip(weights, biases)))
-
-
-def _svm_forward_batched(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
-                         out_fmt: FxpFormat, qgamma: int, qcoef0: int,
-                         degree: int, dec_shift: int):
-    """The decision function on model-stacked values (be, bm, F) -> (be, bm,
-    C): the same algebra as :func:`_svm_forward` with the model axis riding
-    as a dot_general batch dimension (models never mix)."""
-    dot = jax.lax.dot_general(
-        qx.astype(jnp.int32), qsv.astype(jnp.int32),
-        (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32)
-    dot = fixedpoint.requantize(dot, fmt.frac_bits, fmt)
-    g = jnp.asarray(qgamma, fmt.dtype)
-    if kind == "poly":
-        k = fixedpoint.qadd(fixedpoint.qmul(dot, g, fmt),
-                            jnp.asarray(qcoef0, fmt.dtype), fmt)
-        k = fixedpoint.qpow_int(k, degree, fmt)
-    else:  # rbf
-        def _qsq_norm(qv):
-            wide = qv.astype(fmt.wide_dtype)
-            acc = jnp.sum(wide * wide, axis=-1)
-            return fixedpoint.rshift_round_saturate(acc, fmt)
-
-        x2 = _qsq_norm(qx)
-        sv2 = _qsq_norm(qsv)
-        d2 = fixedpoint.qadd(
-            fixedpoint.qsub(x2[:, :, None],
-                            fixedpoint.qadd(dot, dot, fmt), fmt),
-            sv2[:, None, :], fmt)
-        arg = fixedpoint.qneg(fixedpoint.qmul(d2, g, fmt), fmt)
-        k = fixedpoint.qexp(arg, fmt)
-    acc = jax.lax.dot_general(
-        k.astype(jnp.int32), dual.astype(jnp.int32),
-        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.int32)
-    out = fixedpoint.requantize(acc, dec_shift, out_fmt)
-    out = fixedpoint.qadd(out, icept[:, None, :], out_fmt)
-    return out.astype(out_fmt.dtype)
 
 
 def _svm_fleet_kernel(x_ref, sv_ref, dual_ref, icept_ref, o_ref, *,
@@ -536,20 +543,20 @@ def _svm_fleet_kernel(x_ref, sv_ref, dual_ref, icept_ref, o_ref, *,
     uniq, indices = _uniq_branches(params)
     if len(uniq) == 1:
         fmt, out_fmt, qgamma, qcoef0, degree, dec_shift = uniq[0]
-        o_ref[...] = _svm_forward_batched(
+        o_ref[...] = _svm_decision(
             x_ref[...], sv_ref[...], dual_ref[...], icept_ref[...],
             kind=kind, fmt=fmt, out_fmt=out_fmt, qgamma=qgamma,
-            qcoef0=qcoef0, degree=degree, dec_shift=dec_shift)
+            qcoef0=qcoef0, degree=degree, dec_shift=dec_shift, batched=True)
         return
 
     def _branch(p):
         fmt, out_fmt, qgamma, qcoef0, degree, dec_shift = p
 
         def run(qx, qsv, dual, icept):
-            return _svm_forward(qx, qsv, dual, icept, kind=kind, fmt=fmt,
-                                out_fmt=out_fmt, qgamma=qgamma,
-                                qcoef0=qcoef0, degree=degree,
-                                dec_shift=dec_shift)
+            return _svm_decision(qx, qsv, dual, icept, kind=kind, fmt=fmt,
+                                 out_fmt=out_fmt, qgamma=qgamma,
+                                 qcoef0=qcoef0, degree=degree,
+                                 dec_shift=dec_shift, batched=False)
         return run
 
     out = jax.lax.switch(
@@ -583,17 +590,19 @@ def fxp_svm_fleet_pallas(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     assert e % be == 0 and m % bm == 0, (qx.shape, be, bm)
     out_fmt = params[0][1]
 
+    rows = index_map(lambda ei, mi: (ei, mi, 0))
+    member = index_map(lambda ei, mi: (ei, 0, 0))
     return pl.pallas_call(
         functools.partial(_svm_fleet_kernel, kind=kind, params=params,
                           be=be),
         grid=(e // be, m // bm),
         in_specs=[
-            pl.BlockSpec((be, bm, f), lambda ei, mi: (ei, mi, 0)),
-            pl.BlockSpec((be, s, f), lambda ei, mi: (ei, 0, 0)),
-            pl.BlockSpec((be, s, c), lambda ei, mi: (ei, 0, 0)),
-            pl.BlockSpec((be, c), lambda ei, mi: (ei, 0)),
+            pl.BlockSpec((be, bm, f), rows),
+            pl.BlockSpec((be, s, f), member),
+            pl.BlockSpec((be, s, c), member),
+            pl.BlockSpec((be, 1, c), member),
         ],
-        out_specs=pl.BlockSpec((be, bm, c), lambda ei, mi: (ei, mi, 0)),
+        out_specs=pl.BlockSpec((be, bm, c), rows),
         out_shape=jax.ShapeDtypeStruct((e, m, c), out_fmt.dtype),
         interpret=interpret,
-    )(qx, sv, dual, icept)
+    )(qx, sv, dual, icept.reshape(e, 1, c))

@@ -5,9 +5,10 @@ fixed-point matmul semantics — with MXU-friendly tiling:
 
 * grid = (M/bm, N/bn, K/bk); K is the innermost (sequential) axis so each
   (i, j) output tile accumulates into a VMEM int32 scratch across K steps.
-* A/B tiles are staged HBM->VMEM by ``BlockSpec``; the int8/int16 operands
-  feed the MXU's integer path (int32 accumulation), the final rounded shift
-  and saturation run on the VPU at the last K step.
+* A/B tiles are staged HBM->VMEM by ``BlockSpec``; the operands feed the
+  MXU's int8 x int8 -> int32 path through ``mosaic.mxu_dot`` (int16 operands
+  as 7-bit limbs), the final rounded shift and saturation run on the VPU at
+  the last K step.
 * block sizes default to 128/256 multiples (MXU alignment).
 
 The pure-jnp oracle is :func:`repro.kernels.ref.fxp_qmatmul_ref`; tests sweep
@@ -21,6 +22,10 @@ units, i.e. |q| << qmax).  Inputs saturating the container near qmax over
 long K can wrap the accumulator — same failure mode as libfixmath's 32-bit
 accumulate on MCUs; callers needing full-range int16 sums should use the
 xla reference path (ops.fxp_qmatmul(impl='xla')).
+
+32-bit containers have no exact MXU form (their int64 intermediates do not
+exist in Mosaic): they run in interpret mode only, and the lowerings refuse
+them on a TPU.
 """
 
 from __future__ import annotations
@@ -35,8 +40,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import fixedpoint
 from repro.core.fixedpoint import FxpFormat
 
-__all__ = ["fxp_qmatmul_pallas"]
+from .mosaic import mxu_dot, vpu_format
 
+__all__ = ["fxp_qmatmul_pallas"]
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, fmt: FxpFormat, k_steps: int):
     k = pl.program_id(2)
@@ -45,16 +51,14 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, fmt: FxpFormat, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    acc_ref[...] += mxu_dot(a_ref[...], b_ref[...], (((1,), (0,)), ((), ())))
 
     @pl.when(k == k_steps - 1)
     def _finish():
         # The shared accumulator epilogue (one definition of the rounding
         # rule across kernels and oracles), traced onto the VPU.
-        o_ref[...] = fixedpoint.rshift_round_saturate(acc_ref[...], fmt)
+        o_ref[...] = fixedpoint.rshift_round_saturate(acc_ref[...],
+                                                      vpu_format(fmt))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "bm", "bn", "bk",

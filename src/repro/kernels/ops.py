@@ -1,10 +1,12 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Each wrapper pads to block multiples, dispatches to the kernel (interpret
-mode automatically when not running on TPU — this container validates on
-CPU), and unpads.  These are the entry points the rest of the framework
-uses; swapping ``impl='xla'`` falls back to the pure-jnp reference, which is
-also how the dry-run lowers (Mosaic kernels only lower on real TPU).
+Each wrapper pads to block multiples, dispatches to the kernel, and
+unpads.  :func:`repro.kernels.tune.on_tpu` decides once per call whether
+the kernel compiles with Mosaic (lane-padded operands) or runs in interpret
+mode (unpadded, on the CPU); the padding helpers take that decision as an
+argument.  These are the entry points the rest of the framework uses;
+``impl='xla'`` selects the pure-jnp reference instead, which is also how
+the dry-run lowers.
 
 Block sizes are no longer fixed 128/256 defaults: matmul-shaped ops consult
 the :mod:`repro.kernels.tune` autotuner (shape/dtype-keyed, JSON disk
@@ -40,15 +42,11 @@ from .fxp_model import (fxp_mlp_fleet_pallas, fxp_mlp_model_pallas,
                         fxp_svm_fleet_pallas, fxp_svm_model_pallas)
 from .fxp_qmatmul import fxp_qmatmul_pallas
 from .pwl_activation import pwl_activation_pallas
-from .tree_ensemble import pack_tree, tree_ensemble_pallas
+from .tree_ensemble import pack_tree, tree_ensemble_pallas, tree_vmem_bytes
 
 __all__ = ["fxp_qmatmul", "fxp_layer", "fxp_mlp_model", "fxp_svm_model",
            "fxp_mlp_fleet", "fxp_svm_fleet", "pwl_activation",
            "tree_predict", "flash_attention", "count_dispatches"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------------------
@@ -106,14 +104,21 @@ def _pad_matmul(a: jax.Array, b: jax.Array, blocks: tune.Blocks):
 
 def _timed_runner(make_call):
     """Best-of-3 wall-time of a zero-input kernel call (on-TPU tuning only;
-    timing is shape-dependent, not value-dependent, so zeros suffice)."""
+    timing is shape-dependent, not value-dependent, so zeros suffice).
+
+    The wrappers are usually called while an artifact's predict is being
+    traced under ``jax.jit``; each candidate is compiled ahead of time as
+    a program of its own (zeros and constants inside it), so it runs on
+    the device even then.
+    """
 
     def run(blocks: tune.Blocks) -> float:
-        make_call(blocks).block_until_ready()  # compile + warm
+        call = jax.jit(lambda: make_call(blocks)).lower().compile()
+        call().block_until_ready()  # warm
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
-            make_call(blocks).block_until_ready()
+            call().block_until_ready()
             best = min(best, time.perf_counter() - t0)
         return best
 
@@ -135,7 +140,7 @@ def _tuning_operands(m: int, k: int, n: int, fmt: FxpFormat,
 def _matmul_tuning(kind: str, m: int, k: int, n: int, fmt: FxpFormat,
                    make_call=None) -> tune.Blocks:
     runner = None
-    if make_call is not None and _on_tpu():
+    if make_call is not None and tune.on_tpu():
         runner = _timed_runner(make_call)
     return tune.matmul_blocks(kind, m, k, n, fmt.total_bits, runner)
 
@@ -165,7 +170,7 @@ def fxp_qmatmul(a: jax.Array, b: jax.Array, fmt: FxpFormat,
     bm, bn, bk = blocks
     ap, bp, m0, n0 = _pad_matmul(a, b, blocks)
     out = fxp_qmatmul_pallas(ap, bp, fmt, bm=bm, bn=bn, bk=bk,
-                             interpret=not _on_tpu())
+                             interpret=not tune.on_tpu())
     return out[:m0, :n0]
 
 
@@ -201,7 +206,7 @@ def fxp_layer(a: jax.Array, w: jax.Array, bias: jax.Array, fmt: FxpFormat,
     ap, wp, m0, n0 = _pad_matmul(a, w, blocks)
     biasp, _ = _pad_axis(bias, 0, bn)
     out = fxp_layer_pallas(ap, wp, biasp, fmt, activation, shift=shift,
-                           bm=bm, bn=bn, bk=bk, interpret=not _on_tpu())
+                           bm=bm, bn=bn, bk=bk, interpret=not tune.on_tpu())
     return out[:m0, :n0]
 
 
@@ -230,14 +235,15 @@ def fxp_mlp_model(x: jax.Array, weights, biases,
     m = x.shape[0]
     dims = (x.shape[1],) + tuple(w.shape[1] for w in weights)
     bits = schedule[0][1].total_bits
+    chip = tune.on_tpu()
     if bm is None:
         runner = None
-        if _on_tpu():
+        if chip:
             def make_call(blk):
                 zx, zws, zbs = _padded_model_operands(
                     jnp.zeros((tune.batch_bucket(m, cap=1 << 30), dims[0]),
                               x.dtype),
-                    weights, biases)
+                    weights, biases, chip)
                 return fxp_mlp_model_pallas(zx, zws, zbs, schedule, bm=blk)
 
             runner = _timed_runner(make_call)
@@ -246,22 +252,23 @@ def fxp_mlp_model(x: jax.Array, weights, biases,
             vmem_bytes=lambda b: fxp_model.mlp_vmem_bytes(dims, bits, b),
             budget=fxp_model.vmem_budget(), runner=runner)
     xp, m0 = _pad_axis(x, 0, bm)
-    xp, wp, bp = _padded_model_operands(xp, weights, biases)
+    xp, wp, bp = _padded_model_operands(xp, weights, biases, chip)
     n0 = weights[-1].shape[1]
     out = fxp_mlp_model_pallas(xp, wp, bp, schedule, bm=bm,
-                               interpret=not _on_tpu())
+                               interpret=not chip)
     return out[:m0, :n0]
 
 
-def _padded_model_operands(x, weights, biases):
-    """Lane-tile the megakernel's feature axes on real TPU (no-op off TPU:
-    interpret mode has no tile floors and padding is pure waste there).
+def _padded_model_operands(x, weights, biases, chip: bool):
+    """Lane-tile the megakernel's feature axes for the chip (``chip`` =
+    :func:`tune.on_tpu`; a no-op for interpret mode, which has no tile
+    floors and where padding is pure waste).
 
     Zero padding is bit-safe end to end — padded feature columns meet zero
     weight rows, padded hidden lanes feed zero rows of the next layer, and
     the wrapper slices padded outputs off before anyone can read them.
     """
-    if not _on_tpu():
+    if not chip:
         return x, tuple(weights), tuple(biases)
     xp, _ = _pad_axis(x, 1, _LANE)
     ws, bs = [], []
@@ -295,13 +302,14 @@ def fxp_svm_model(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     m, n_feat = qx.shape
     n_sv, n_cls = dual.shape
     bits = fmt.total_bits
+    chip = tune.on_tpu()
     if bm is None:
         runner = None
-        if _on_tpu():
+        if chip:
             def make_call(blk):
                 zx, zsv, zd, zi = _padded_svm_operands(
                     jnp.zeros((tune.batch_bucket(m, cap=1 << 30), n_feat),
-                              qx.dtype), sv, dual, icept)
+                              qx.dtype), sv, dual, icept, chip)
                 return fxp_svm_model_pallas(zx, zsv, zd, zi, kind, fmt,
                                             out_fmt, qgamma, qcoef0, degree,
                                             dec_shift, bm=blk)
@@ -313,22 +321,23 @@ def fxp_svm_model(qx: jax.Array, sv: jax.Array, dual: jax.Array,
                 n_sv, n_feat, n_cls, bits, b),
             budget=fxp_model.vmem_budget(), runner=runner)
     xp, m0 = _pad_axis(qx, 0, bm)
-    xp, svp, dp, ip = _padded_svm_operands(xp, sv, dual, icept)
+    xp, svp, dp, ip = _padded_svm_operands(xp, sv, dual, icept, chip)
     out = fxp_svm_model_pallas(xp, svp, dp, ip, kind, fmt, out_fmt, qgamma,
                                qcoef0, degree, dec_shift, bm=bm,
-                               interpret=not _on_tpu())
+                               interpret=not chip)
     return out[:m0, :n_cls]
 
 
-def _padded_svm_operands(qx, sv, dual, icept):
-    """Lane-tile the SVM megakernel operands on real TPU (no-op off TPU).
+def _padded_svm_operands(qx, sv, dual, icept, chip: bool):
+    """Lane-tile the SVM megakernel operands for the chip (no-op for
+    interpret mode).
 
     Padded support-vector *rows* produce nonzero kernel values (e.g. the
     rbf kernel of an all-zero vector), but their dual-coefficient rows are
     zero, so they contribute nothing to the decision — zero padding stays
     bit-safe.
     """
-    if not _on_tpu():
+    if not chip:
         return qx, sv, dual, icept
     xp, _ = _pad_axis(qx, 1, _LANE)
     svp, _ = _pad_axis(sv, 0, _LANE)
@@ -379,16 +388,18 @@ def fxp_mlp_fleet(x: jax.Array, weights, biases, schedules,
         weights = tuple(_pad_axis(w, 0, be)[0] for w in weights)
         biases = tuple(_pad_axis(b, 0, be)[0] for b in biases)
         schedules = schedules + (schedules[0],) * rem
-    xp, wp, bp = _padded_fleet_mlp_operands(xp, weights, biases)
+    chip = tune.on_tpu()
+    xp, wp, bp = _padded_fleet_mlp_operands(xp, weights, biases, chip)
     out = fxp_mlp_fleet_pallas(xp, wp, bp, schedules, be=be, bm=bm,
-                               interpret=not _on_tpu())
+                               interpret=not chip)
     return out[:e, :m0, :dims[-1]]
 
 
-def _padded_fleet_mlp_operands(x, weights, biases):
-    """Lane-tile the fleet megakernel's feature axes on real TPU (no-op off
-    TPU) — the model axis is never tiled, only the trailing feature dims."""
-    if not _on_tpu():
+def _padded_fleet_mlp_operands(x, weights, biases, chip: bool):
+    """Lane-tile the fleet megakernel's feature axes for the chip (no-op
+    for interpret mode) — the model axis is never tiled, only the trailing
+    feature dims."""
+    if not chip:
         return x, tuple(weights), tuple(biases)
     xp, _ = _pad_axis(x, 2, _LANE)
     ws, bs = [], []
@@ -439,16 +450,17 @@ def fxp_svm_fleet(qx: jax.Array, sv: jax.Array, dual: jax.Array,
         dual, _ = _pad_axis(dual, 0, be)
         icept, _ = _pad_axis(icept, 0, be)
         params = params + (params[0],) * rem
-    xp, svp, dp, ip = _padded_fleet_svm_operands(xp, sv, dual, icept)
+    chip = tune.on_tpu()
+    xp, svp, dp, ip = _padded_fleet_svm_operands(xp, sv, dual, icept, chip)
     out = fxp_svm_fleet_pallas(xp, svp, dp, ip, kind, params, be=be, bm=bm,
-                               interpret=not _on_tpu())
+                               interpret=not chip)
     return out[:e, :m0, :n_cls]
 
 
-def _padded_fleet_svm_operands(qx, sv, dual, icept):
-    """Lane-tile the SVM fleet operands' trailing dims on real TPU (no-op
-    off TPU); the model axis is never tiled."""
-    if not _on_tpu():
+def _padded_fleet_svm_operands(qx, sv, dual, icept, chip: bool):
+    """Lane-tile the SVM fleet operands' trailing dims for the chip (no-op
+    for interpret mode); the model axis is never tiled."""
+    if not chip:
         return qx, sv, dual, icept
     xp, _ = _pad_axis(qx, 2, _LANE)
     svp, _ = _pad_axis(sv, 1, _LANE)
@@ -476,7 +488,7 @@ def pwl_activation(x: jax.Array, variant: str = "pwl4",
     flat, n0 = _pad_axis(flat, 0, block_rows * cols)
     x2 = flat.reshape(-1, cols)
     out = pwl_activation_pallas(x2, variant, block_rows=block_rows,
-                                block_cols=cols, interpret=not _on_tpu())
+                                block_cols=cols, interpret=not tune.on_tpu())
     return out.reshape(-1)[:n0].reshape(orig_shape)
 
 
@@ -508,9 +520,12 @@ def _packed_operands(tree: TreeArrays) -> tuple:
     # Memoize device-resident copies once we are outside any trace (a
     # concrete device array is a legal jit constant, so later traced calls
     # reuse it too); the eager serving hot path then never re-uploads the
-    # packed operands per dispatch.
-    if "dev" not in entry and jax.core.trace_state_clean():
-        entry["dev"] = tuple(jnp.asarray(t) for t in entry["np"])
+    # packed operands per dispatch.  Inside a trace the copies come back
+    # as tracers, which are never kept.
+    if "dev" not in entry:
+        dev = tuple(jnp.asarray(t) for t in entry["np"])
+        if not any(isinstance(d, jax.core.Tracer) for d in dev):
+            entry["dev"] = dev
     return entry.get("dev", entry["np"])
 
 
@@ -520,16 +535,22 @@ def tree_predict(tree: TreeArrays, x: jax.Array, impl: str = "pallas",
     _tick()
     if impl in ("xla", "ref"):
         return ref_ops.tree_ensemble_ref(tree, x)
-    sel, thr, ppos, pneg, plen, classes = _packed_operands(tree)
+    packed = _packed_operands(tree)
     # The block shrinks with the batch so tiny calls stay on one grid step,
     # but only to the batch's pow2 *bucket* (the serve/batching.py ladder),
     # and ragged batches are padded up to the bucket *here* — the jitted
     # kernel only ever sees bucket-shaped inputs, so a warm bucket hits the
     # jit cache instead of recompiling per distinct B.
     bb = tune.batch_bucket(x.shape[0], cap=block_batch)
+    (f, n), l = packed[0].shape, packed[2].shape[1]
+    if tree_vmem_bytes(f, n, l, bb) > tune._VMEM_BUDGET:
+        raise ValueError(
+            f"tree of {n} nodes x {l} leaves over {f} features needs "
+            f"{tree_vmem_bytes(f, n, l, bb)} B of VMEM per step, over the "
+            f"{tune._VMEM_BUDGET} B budget; serve it on 'xla'")
     xp, b0 = _pad_axis(jnp.asarray(x, jnp.float32), 0, bb)
-    out = tree_ensemble_pallas(xp, sel, thr, ppos, pneg, plen, classes,
-                               block_batch=bb, interpret=not _on_tpu())
+    out = tree_ensemble_pallas(xp, *packed, block_batch=bb,
+                               interpret=not tune.on_tpu())
     return out[:b0]
 
 
@@ -541,4 +562,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl in ("xla", "ref"):
         return ref_ops.flash_attention_ref(q, k, v, causal)
     return flash_attention_pallas(q, k, v, causal=causal, bq=bq, bk=bk,
-                                  interpret=not _on_tpu())
+                                  interpret=not tune.on_tpu())

@@ -11,21 +11,27 @@ This module picks (bm, bn, bk) per *problem shape bucket* instead:
   and one jit trace.
 * **Selection** — on TPU, candidates are swept with a caller-provided
   ``runner`` (wall-time of the real kernel on zero inputs of the padded
-  shape; timing is shape- not value-dependent) and the fastest wins.  Off
-  TPU (interpret mode — CI, laptops) timing is meaningless, so a
-  deterministic cost model picks the candidate minimizing padded MACs plus
-  a small per-grid-step overhead charge.
+  shape; timing is shape- not value-dependent) and the fastest wins.  A
+  candidate the compiler refuses is dropped and counted
+  (:func:`dropped_candidates`), never silently.  Off TPU (interpret mode —
+  CI, laptops) timing is meaningless, so a deterministic cost model picks
+  the candidate minimizing padded MACs plus a small per-grid-step overhead
+  charge.
 * **Cache** — two layers: a process-wide dict, and an on-disk JSON file
-  (``$REPRO_TUNE_CACHE`` or ``~/.cache/repro/tune_cache.json``) written
-  atomically on every new entry, so tuning survives process restarts and a
-  serving fleet can ship a pre-tuned cache.  Delete the file (or point the
-  env var elsewhere) to invalidate; ``CompiledArtifact.pretune`` fills it
-  ahead of traffic.
+  (``$REPRO_TUNE_CACHE`` or ``.repro_cache/tune_cache.json`` in the
+  checkout) written atomically on every new entry, so tuning survives
+  process restarts and a serving fleet can ship a pre-tuned cache.  Delete
+  the file (or point the env var elsewhere) to invalidate;
+  ``CompiledArtifact.pretune`` fills it ahead of traffic.
 
 Candidates respect TPU tiling floors (sublane x lane = {8,16,32} x 128 by
 container width) when tuning for a real TPU; interpret mode may shrink
 blocks all the way to the problem size, since only padded-work waste
 matters there.
+
+:func:`on_tpu` is the kernel layer's one platform decision: it picks
+Mosaic compilation over interpret mode, lane-padded operands, the TPU tile
+floors above and timed tuning.  Everything else takes it as an argument.
 """
 
 from __future__ import annotations
@@ -34,13 +40,17 @@ import contextlib
 import json
 import os
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 
+from repro.jax_cache import CHECKOUT
+
 __all__ = ["matmul_blocks", "model_block_m", "fleet_blocks", "batch_bucket",
            "pwl_blocks", "pow2ceil", "cache_path", "clear_memory_cache",
-           "cache_snapshot", "device_key"]
+           "cache_snapshot", "device_key", "on_tpu", "dropped_candidates",
+           "tuning_seconds"]
 
 Blocks = Tuple[int, int, int]
 Runner = Callable[[Blocks], float]
@@ -57,6 +67,32 @@ _TPU_SUBLANE = {32: 8, 16: 16, 8: 32}
 _lock = threading.RLock()
 _memory: Dict[str, Blocks] = {}
 _disk_loaded_from: Optional[str] = None
+# Candidates a timed sweep dropped because the compiler (or the run) refused
+# them, as "key blocks: error" lines, and the seconds spent sweeping.
+_dropped: List[str] = []
+_sweep_s = [0.0]
+
+
+def on_tpu() -> bool:
+    """True when the kernels compile for the TPU with Mosaic.
+
+    The single platform decision of the kernel layer: interpret mode,
+    lane padding, the tuner's tile floors and timed sweeps all follow it.
+    Helpers that shape operands take the answer as an argument, so a test
+    can build exactly the shapes the chip compiles.
+    """
+    return jax.default_backend() == "tpu"
+
+
+def dropped_candidates() -> List[str]:
+    """Every candidate a timed sweep dropped in this process, with why."""
+    with _lock:
+        return list(_dropped)
+
+
+def tuning_seconds() -> float:
+    """Wall seconds this process spent in timed tuning sweeps."""
+    return _sweep_s[0]
 
 
 # --------------------------------------------------------------------------
@@ -111,8 +147,7 @@ def pwl_blocks(n_elements: int) -> Tuple[int, int]:
 def cache_path() -> str:
     return os.environ.get(
         "REPRO_TUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "tune_cache.json"))
+        os.path.join(CHECKOUT, ".repro_cache", "tune_cache.json"))
 
 
 def _merge_disk_into_memory(path: str) -> None:
@@ -247,19 +282,35 @@ def _model_cost(m: int, k: int, n: int, blocks: Blocks) -> float:
     return mp * kp * np_ + steps * _STEP_COST
 
 
-def _choose(m: int, k: int, n: int, bits: int,
+def _sweep(key: str, cands: list, runner: Callable):
+    """The fastest candidate by ``runner`` wall time, or None when every
+    candidate was refused.  Refusals are recorded, not swallowed.  A lone
+    candidate is returned untimed: there is nothing to choose."""
+    if len(cands) == 1:
+        return cands[0]
+    t0 = time.perf_counter()
+    best, best_t = None, float("inf")
+    for cand in cands:
+        try:
+            t = runner(cand)
+        except Exception as e:
+            msg = (str(e).strip().splitlines() or [""])[0][:200]
+            with _lock:
+                _dropped.append(f"{key} {cand}: {type(e).__name__}: {msg}")
+            continue
+        if t < best_t:
+            best, best_t = cand, t
+    with _lock:
+        _sweep_s[0] += time.perf_counter() - t0
+    return best
+
+
+def _choose(key: str, m: int, k: int, n: int, bits: int,
             runner: Optional[Runner]) -> Blocks:
-    on_tpu = jax.default_backend() == "tpu"
-    cands = candidates(m, k, n, bits, on_tpu)
-    if on_tpu and runner is not None:
-        best, best_t = None, float("inf")
-        for blocks in cands:
-            try:
-                t = runner(blocks)
-            except Exception:
-                continue  # candidate rejected by the compiler: skip
-            if t < best_t:
-                best, best_t = blocks, t
+    chip = on_tpu()
+    cands = candidates(m, k, n, bits, chip)
+    if chip and runner is not None:
+        best = _sweep(key, cands, runner)
         if best is not None:
             return best
     # Deterministic fallback (and the only path off-TPU).
@@ -298,7 +349,7 @@ def matmul_blocks(kind: str, m: int, k: int, n: int, bits: int,
     # candidates, and holding the lock through it would stall every other
     # thread's warm dict hit.  A concurrent miss on the same key tunes
     # twice and stores the same (deterministic off-TPU) answer — harmless.
-    blocks = _choose(mb, int(k), int(n), int(bits), runner)
+    blocks = _choose(key, mb, int(k), int(n), int(bits), runner)
     with _lock:
         blocks = _memory.setdefault(key, blocks)
     _save_disk()  # outside _lock: the cross-process flock must not stall hits
@@ -333,8 +384,8 @@ def model_block_m(kind: str, m: int, dims: Tuple[int, ...], bits: int,
             hit = _memory.get(key)
         if hit is not None:
             return int(hit[0])
-    on_tpu = jax.default_backend() == "tpu"
-    floor = _TPU_SUBLANE[int(bits)] if on_tpu else 1
+    chip = on_tpu()
+    floor = _TPU_SUBLANE[int(bits)] if chip else 1
     cap = max(floor, min(128, pow2ceil(mb)))
     cands = _pow2s_upto(cap, floor)
     if vmem_bytes is not None:
@@ -342,15 +393,8 @@ def model_block_m(kind: str, m: int, dims: Tuple[int, ...], bits: int,
         fitting = [bm for bm in cands if vmem_bytes(bm) <= limit]
         cands = fitting or cands[:1]  # callers gate on the fit predicate
     bm = cands[-1]
-    if on_tpu and runner is not None:
-        best_t = float("inf")
-        for cand in cands:
-            try:
-                t = runner(cand)
-            except Exception:
-                continue  # candidate rejected by the compiler: skip
-            if t < best_t:
-                bm, best_t = cand, t
+    if chip and runner is not None:
+        bm = _sweep(key, cands, runner) or bm
     with _lock:
         got = _memory.setdefault(key, (int(bm), 1, 1))
     _save_disk()
@@ -391,8 +435,8 @@ def fleet_blocks(kind: str, n_models: int, m: int, dims: Tuple[int, ...],
             hit = _memory.get(key)
         if hit is not None:
             return int(hit[0]), int(hit[1])
-    on_tpu = jax.default_backend() == "tpu"
-    floor = _TPU_SUBLANE[int(bits)] if on_tpu else 1
+    chip = on_tpu()
+    floor = _TPU_SUBLANE[int(bits)] if chip else 1
     bms = _pow2s_upto(max(floor, min(128, pow2ceil(mb))), floor)
     bes = ([b for b in _pow2s_upto(pow2ceil(e), 1) if b <= e]
            if uniform else [1])
@@ -412,15 +456,8 @@ def fleet_blocks(kind: str, n_models: int, m: int, dims: Tuple[int, ...],
         return ep * mp * row_macs + steps * _STEP_COST
 
     be, bm = min(cands, key=lambda c: (_cost(c), -(c[0] * c[1])))
-    if on_tpu and runner is not None:
-        best_t = float("inf")
-        for cand in cands:
-            try:
-                t = runner(cand)
-            except Exception:
-                continue  # candidate rejected by the compiler: skip
-            if t < best_t:
-                (be, bm), best_t = cand, t
+    if chip and runner is not None:
+        be, bm = _sweep(key, cands, runner) or (be, bm)
     with _lock:
         got = _memory.setdefault(key, (int(be), int(bm), 1))
     _save_disk()

@@ -39,7 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, get_config
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.lm import model as model_lib
 from repro.roofline.analysis import (HW, collective_bytes_from_hlo,
                                      model_flops, roofline_terms)
@@ -173,7 +173,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, quantized: bool = False
     if mesh_name.startswith("dp"):
         # custom single-pod mesh 'dp<D>tp<T>' e.g. dp64tp4 (perf iterations)
         dpn, tpn = mesh_name[2:].split("tp")
-        mesh = jax.make_mesh((int(dpn), int(tpn)), ("data", "model"))
+        mesh = make_mesh((int(dpn), int(tpn)), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=mesh_name == "multipod")
     chips = int(np.prod(list(mesh.shape.values())))

@@ -10,8 +10,18 @@ import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_ci_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_ci_mesh"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto mode: the sharding rules
+    place arrays with NamedSharding constraints and let the partitioner
+    resolve the rest (``jax.make_mesh`` now defaults to Explicit axes,
+    under which e.g. an embedding gather needs an explicit out sharding).
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,10 +33,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_ci_mesh(n_devices: int = 8):
     """Small mesh for CI-scale dry-run tests (data x model)."""
     d = max(1, n_devices // 2)
-    return jax.make_mesh((d, n_devices // d), ("data", "model"))
+    return make_mesh((d, n_devices // d), ("data", "model"))

@@ -143,6 +143,9 @@ def serve_classifier(args) -> None:
 
 
 def main(argv=None):
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--classifier", choices=["tree", "mlp", "logistic"],

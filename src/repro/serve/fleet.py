@@ -21,7 +21,10 @@ Per-endpoint semantics are preserved, not flattened:
   record success on every riding member's breaker;
 * **fault isolation** — a stacked dispatch failure falls back to
   per-member serving (retries, poison bisection and all); one member's
-  malformed rows never fail another member's round.
+  malformed rows never fail another member's round.  Every such fallback,
+  and every warmup trace that failed, is counted in :meth:`snapshot`
+  (``stack_fallbacks``, ``warmup_failures``): a stack that never runs must
+  not look like a working fleet.
 
 Overlap: the stacked dispatch is launched *asynchronously* (JAX async
 dispatch — ``FleetStack.predict_device`` returns an unmaterialized device
@@ -91,6 +94,7 @@ class FleetCoalescer:
         self.n_stacked_requests = 0
         self.n_solo_batches = 0        # member batches served per-endpoint
         self.n_stack_fallbacks = 0     # stacked rounds re-served per member
+        self.n_warmup_failures = 0     # warmup traces that raised
         self.assembly_s = 0.0          # host staging-buffer assembly time
         self.device_s = 0.0            # launch -> materialized outputs
         for ep in self.members:
@@ -128,6 +132,7 @@ class FleetCoalescer:
                 "stacked_requests": self.n_stacked_requests,
                 "solo_batches": self.n_solo_batches,
                 "stack_fallbacks": self.n_stack_fallbacks,
+                "warmup_failures": self.n_warmup_failures,
                 "staging_allocs": self.n_staging_allocs,
                 "assembly_s": self.assembly_s,
                 "device_s": self.device_s}
@@ -158,14 +163,16 @@ class FleetCoalescer:
                 np.asarray(self.stack.predict_device(
                     np.zeros((shape[0], b, shape[1]), np.float32)))
             except Exception:
-                pass  # live rounds surface the error with fallback
+                # Live rounds surface the error with fallback; counted.
+                self.n_warmup_failures += 1
         example = np.zeros((1, shape[1]), np.float32)
         for ep in self.members:
             if ep.batcher.policy.warmup and not ep.batcher._warmed:
                 try:
                     ep.batcher._warmup(example)
                 except Exception:
-                    pass  # solo serving will retry with real rows
+                    # Solo serving will retry with real rows; counted.
+                    self.n_warmup_failures += 1
         self._warmed = True
 
     def _serve_solo(self, ep, batch: list) -> None:

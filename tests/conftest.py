@@ -7,9 +7,19 @@ warnings.filterwarnings("ignore", message=".*int64.*")
 
 
 @pytest.fixture(scope="session", autouse=True)
+def _no_persistent_compile_cache():
+    """Entry points the suite drives (the serve CLI) turn JAX's persistent
+    compilation cache on; the suite itself writes none into the checkout."""
+    import jax
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+
+
+@pytest.fixture(scope="session", autouse=True)
 def _isolated_tune_cache(tmp_path_factory):
     """Keep the kernel block-size tuner hermetic: the suite must neither
-    read a developer's warm ~/.cache entries nor write into them."""
+    read a developer's warm tuning entries nor write into them."""
     import os
 
     from repro.kernels import tune

@@ -261,3 +261,68 @@ def test_discard_params_frees_but_blocks_save(tmp_path, trained, blobs_module):
     np.testing.assert_array_equal(art.predict(xte), before)
     with pytest.raises(ValueError, match="discard_params"):
         art.save(os.path.join(tmp_path, "nope.embml"))
+
+
+# ---------------------------------------------------------------------------
+# 32-bit containers on pallas have no exact TPU program
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("name", ["logistic", "mlp", "svm-linear", "svm-rbf"])
+def test_pallas_fxp32_refused_on_tpu(trained, name, monkeypatch):
+    """On a TPU, a 32-bit pallas Target fails at compile time and names
+    itself — it never runs somewhere else quietly.  Off the TPU the same
+    Target compiles (interpret mode holds the int64 intermediates)."""
+    from repro.kernels import tune
+
+    target = Target(number_format="fxp32", backend="pallas")
+    assert compile(trained[name], target).target == target
+    monkeypatch.setattr(tune, "on_tpu", lambda: True)
+    with pytest.raises(NotImplementedError, match="number_format='fxp32'"):
+        compile(trained[name], target)
+    # trees compare floats in their kernel: no int64 inside, still served
+    assert compile(trained["tree"], target).kind == "tree"
+
+
+# ---------------------------------------------------------------------------
+# the entry points' persistent compile cache
+# ---------------------------------------------------------------------------
+def test_compile_cache_lands_only_in_env_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiled programs land there
+    and nowhere in the checkout."""
+    import subprocess
+    import sys
+
+    from repro.jax_cache import CHECKOUT
+
+    local = os.path.join(CHECKOUT, ".jax_cache")
+    before = set(os.listdir(local)) if os.path.isdir(local) else set()
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(CHECKOUT, "src"))
+    code = ("from repro.jax_cache import enable_compile_cache as e; "
+            "print(e()); import jax, jax.numpy as jnp; "
+            "jax.jit(lambda x: x * 3 - 1)(jnp.ones(5)).block_until_ready()")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
+    assert any(n.startswith("jit_") for n in os.listdir(tmp_path))
+    after = set(os.listdir(local)) if os.path.isdir(local) else set()
+    assert after == before
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+
+    from repro.jax_cache import CHECKOUT, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old_dir = jax.config.jax_compilation_cache_dir
+    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        want = os.path.join(CHECKOUT, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          old_min)

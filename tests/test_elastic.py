@@ -20,6 +20,7 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.lm import model as M
     from repro.sharding.rules import Rules
     from repro.train.checkpoint import CheckpointManager
@@ -53,14 +54,14 @@ _SCRIPT = textwrap.dedent("""
     mgr = CheckpointManager(ckpt_dir, keep=2)
     phase = os.environ["ELASTIC_PHASE"]
     if phase == "save":
-        mesh = jax.make_mesh((4, 2), ("data", "model"))   # DP4 x TP2
+        mesh = make_mesh((4, 2), ("data", "model"))   # DP4 x TP2
         params = M.init_params(cfg, jax.random.PRNGKey(0))
         params, opt_state, loss = one_step(mesh, params, opt.init(params))
         mgr.save(1, {"params": jax.tree.map(np.asarray, params)},
                  metadata={"loss": float(loss)})
         print("SAVED", float(loss))
     else:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))   # DP2 x TP4 (re-shard)
+        mesh = make_mesh((2, 4), ("data", "model"))   # DP2 x TP4 (re-shard)
         like = {"params": M.init_params(cfg, jax.random.PRNGKey(0))}
         step_idx, tree, meta = mgr.restore(like)
         params, opt_state, loss = one_step(mesh, tree["params"],

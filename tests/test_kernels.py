@@ -3,6 +3,7 @@
 Shape/dtype sweeps as required: parametrized grids + hypothesis randoms.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -64,6 +65,40 @@ def test_property_fxp_qmatmul(m, k, n, seed):
     b = rng.randint(-3000, 3000, (k, n)).astype(np.int16)
     got = np.asarray(ops.fxp_qmatmul(jnp.asarray(a), jnp.asarray(b), fmt))
     want = np.asarray(R.fxp_qmatmul_ref(jnp.asarray(a), jnp.asarray(b), fmt))
+    np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# mxu_dot — every integer dot in the MXU's int8 x int8 -> int32 form
+# ---------------------------------------------------------------------------
+_DOT_CASES = {
+    "matmul": ((9, 40), (40, 7), (((1,), (0,)), ((), ()))),
+    "x_svT": ((9, 40), (7, 40), (((1,), (1,)), ((), ()))),
+    "batched": ((3, 9, 40), (3, 40, 7), (((2,), (1,)), ((0,), (0,)))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DOT_CASES))
+@pytest.mark.parametrize("dtype", [np.int8, np.int16])
+def test_mxu_dot_equals_wrapping_int32_dot(case, dtype):
+    """int16 limbs recombine to the exact int32 dot modulo 2^32 — full
+    range, extremes included, so sums that wrap int32 wrap identically."""
+    from repro.kernels.mosaic import mxu_dot
+
+    import zlib
+
+    a_shape, b_shape, dims = _DOT_CASES[case]
+    info = np.iinfo(dtype)
+    rng = np.random.RandomState(zlib.crc32(case.encode()))
+    a = rng.randint(info.min, info.max + 1, a_shape).astype(dtype)
+    b = rng.randint(info.min, info.max + 1, b_shape).astype(dtype)
+    a.flat[:4] = (info.min, info.max, info.min, -1)
+    b.flat[:4] = (info.min, info.min, info.max, info.max)
+    got = np.asarray(mxu_dot(jnp.asarray(a), jnp.asarray(b), dims))
+    want = np.asarray(jax.lax.dot_general(
+        jnp.asarray(a, jnp.int32), jnp.asarray(b, jnp.int32), dims,
+        preferred_element_type=jnp.int32))
+    assert got.dtype == np.int32
     np.testing.assert_array_equal(got, want)
 
 

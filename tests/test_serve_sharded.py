@@ -210,8 +210,8 @@ def test_mesh_pretune_walks_replica_ladder(trained, blobs_module, tmp_path,
     sharded = art.specialize_mesh(shrules.make_serving_mesh(), "fused")
     sharded.pretune(xte[0])
     snap = tune.cache_snapshot()
-    layer_keys = [k for k in snap if k.startswith("layer|")]
-    assert layer_keys, "pretune populated no tuner entries"
+    model_keys = [k for k in snap if k.startswith("model-mlp|")]
+    assert model_keys, "pretune populated no tuner entries"
     dev_key = tune.device_key()
     assert all(k.endswith(dev_key) for k in snap), (
         f"tune entries not device-keyed: {sorted(snap)}")

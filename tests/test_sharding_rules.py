@@ -14,7 +14,9 @@ from repro.sharding.rules import Rules  # noqa: E402
 @pytest.fixture(scope="module")
 def mesh():
     # single real CPU device: 1x1 mesh still exercises the rule logic
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_divisibility_guard(mesh):

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import spans
 from repro.core.fixedpoint import FxpStats
 from repro.train.checkpoint import (LEAF_KEY as _LEAF_KEY,
                                     atomic_write_bytes, compress_bytes,
@@ -175,8 +176,11 @@ class CompiledArtifact:
 
     # -- inference -----------------------------------------------------------
     def predict(self, x: np.ndarray) -> np.ndarray:
-        out, _ = self._predict(x)
-        return np.asarray(out, np.int32)
+        rows = len(x)
+        with spans.span("repro.predict.call", rows=rows):
+            out, _ = self._predict(x)
+        with spans.span("repro.predict.sync", rows=rows):
+            return np.asarray(out, np.int32)
 
     def predict_with_stats(self, x: np.ndarray) -> Tuple[np.ndarray, Dict[str, float]]:
         out, stats = self._predict(x)

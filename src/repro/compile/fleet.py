@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import fixedpoint as fxp
 from repro.kernels import fxp_model, ops
 
@@ -127,7 +128,8 @@ class FleetStack:
 
     def predict_device(self, x: np.ndarray) -> Any:
         """One stacked dispatch; returns the async (E, M) device array."""
-        return self._predict_device(x)
+        with spans.span("repro.predict.call", rows=x.size // x.shape[-1]):
+            return self._predict_device(x)
 
     def predict(self, x: np.ndarray) -> np.ndarray:
         return np.asarray(self.predict_device(x), np.int32)

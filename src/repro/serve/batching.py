@@ -47,6 +47,8 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
+
 from .reliability import DeadlineExceeded, DispatchError, RetryPolicy
 
 __all__ = ["BatchingPolicy", "MicroBatcher"]
@@ -155,6 +157,18 @@ class _Request:
     future: Future
     t_enqueue: float
     deadline: Optional[float] = None  # absolute, on the batcher's clock
+    # (request id, perf_counter at enqueue) while spans are on, else None:
+    # the start of the request's ``repro.request.queue`` interval.
+    queued: Optional[Tuple[int, float]] = None
+
+
+def _taken(req: _Request, taker: int) -> None:
+    """Record ``req``'s wait in the queue, ending now, under the span id
+    of the batch or fleet round that took it."""
+    if req.queued is not None:
+        rid, t = req.queued
+        spans.interval("repro.request.queue", t, time.perf_counter(),
+                       request=rid, taker=taker)
 
 
 def _fail(fut: Future, exc: BaseException) -> None:
@@ -269,6 +283,8 @@ class MicroBatcher:
                 f"{self.policy.max_batch}; split it across submissions")
         now = self._clock()
         deadline = None if timeout_s is None else now + max(0.0, timeout_s)
+        queued = ((spans.new_id(), time.perf_counter()) if spans.enabled()
+                  else None)
         fut: Future = Future()
         # The closed check and the enqueue must be atomic vs close(), or a
         # racing submit could land a request in a dead queue after the final
@@ -276,7 +292,7 @@ class MicroBatcher:
         with self._submit_lock:
             if self._closed:
                 raise RuntimeError(f"MicroBatcher '{self.name}' is closed")
-            self._queue.put(_Request(x, fut, now, deadline))
+            self._queue.put(_Request(x, fut, now, deadline, queued))
         cb = self.on_enqueue
         if cb is not None:
             try:
@@ -362,12 +378,11 @@ class MicroBatcher:
             f"deadline passed after {self._clock() - req.t_enqueue:.3f}s in "
             f"queue on '{self.name}'"))
 
-    def _collect(self) -> Optional[list]:
-        """Block for the first live request, then gather until the batch is
-        full or the first request's ``max_wait_ms`` budget runs out.
-        Requests already past their deadline are resolved with
+    def _first(self) -> Optional[_Request]:
+        """Block for the first live request of the next batch.  Requests
+        already past their deadline are resolved with
         :class:`DeadlineExceeded` and never join a batch.  Returns None on
-        shutdown sentinel."""
+        shutdown sentinel or detach."""
         first = self._carry
         self._carry = None
         while True:
@@ -383,9 +398,15 @@ class MicroBatcher:
                 self._carry = first  # hand head-of-line to the driver
                 return None
             if not self._expired(first):
-                break
+                return first
             self._expire(first)
             first = None
+
+    def _gather(self, first: _Request, taker: int) -> list:
+        """Gather a batch behind ``first`` until it is full or the first
+        request's ``max_wait_ms`` budget runs out (expired requests are
+        resolved and skipped); ``taker`` is the batch's span id."""
+        _taken(first, taker)
         batch, rows = [first], first.x.shape[0]
         deadline = first.t_enqueue + self.policy.max_wait_ms / 1e3
         while rows < self.policy.max_batch:
@@ -410,6 +431,7 @@ class MicroBatcher:
             if rows + req.x.shape[0] > self.policy.max_batch:
                 self._carry = req  # head-of-line for the next batch
                 break
+            _taken(req, taker)
             batch.append(req)
             rows += req.x.shape[0]
         return batch
@@ -434,11 +456,13 @@ class MicroBatcher:
                 f"MicroBatcher '{self.name}' worker did not detach")
         self._worker = None
 
-    def collect_nowait(self) -> list:
+    def collect_nowait(self, taker: int = 0) -> list:
         """Gather the next micro-batch without blocking (external drivers
         only — the internal worker must be detached).  Returns possibly-[].
         Honors carry/deadlines/max_batch exactly like the worker's collect;
-        preserves a close() sentinel for the final drain."""
+        preserves a close() sentinel for the final drain.  ``taker`` is the
+        span id of the caller's round, which the requests' queue waits
+        name."""
         batch: list = []
         rows = 0
         first = self._carry
@@ -447,6 +471,7 @@ class MicroBatcher:
             if self._expired(first):
                 self._expire(first)
             else:
+                _taken(first, taker)
                 batch, rows = [first], first.x.shape[0]
         while rows < self.policy.max_batch:
             try:
@@ -464,6 +489,7 @@ class MicroBatcher:
             if rows + req.x.shape[0] > self.policy.max_batch:
                 self._carry = req
                 break
+            _taken(req, taker)
             batch.append(req)
             rows += req.x.shape[0]
         return batch
@@ -474,6 +500,15 @@ class MicroBatcher:
         fallback path.  Single-caller, like the worker loop it replaces."""
         if not batch:
             return
+        with spans.span("repro.batch") as sp:
+            self._serve_collected(batch, sp)
+
+    def _serve_collected(self, batch: list, sp) -> None:
+        """Serve a collected batch under its ``repro.batch`` span ``sp``."""
+        if sp.id:
+            rows = sum(r.x.shape[0] for r in batch)
+            sp.set(requests=len(batch), rows=rows,
+                   bucket=self.policy.bucket_for(rows))
         if self.policy.warmup and not self._warmed:
             self._warmup(batch[0].x)
         self._serve(batch)
@@ -564,44 +599,50 @@ class MicroBatcher:
         rows = sum(r.x.shape[0] for r in batch)
         bucket = self.policy.bucket_for(rows)
         t0 = self._clock()
-        x = self._assemble(batch, rows, bucket)
+        with spans.span("repro.batch.assemble"):
+            x = self._assemble(batch, rows, bucket)
         t1 = self._clock()
-        out = self.predict(x)
-        meta = None
-        if type(out) is tuple:  # (outputs, batch metadata)
-            out, meta = out
-        # np.asarray forces the async device computation — everything after
-        # t1 up to here is dispatch + device time, split from assembly time.
-        y = np.asarray(out)[:rows]
+        with spans.span("repro.batch.dispatch"):
+            out = self.predict(x)
+            meta = None
+            if type(out) is tuple:  # (outputs, batch metadata)
+                out, meta = out
+            # np.asarray forces the async device computation — everything
+            # after t1 up to here is dispatch + device time, split from
+            # assembly time.
+            y = np.asarray(out)[:rows]
         self.assembly_s += t1 - t0
         self.device_s += self._clock() - t1
-        if self._on_dispatch is not None:
-            try:
-                self._on_dispatch(True, None)
-            except Exception:
-                pass
-        done = self._clock()
-        # Stats are recorded BEFORE the futures resolve: a caller woken by
-        # its result (e.g. an HTTP client that immediately queries
-        # /v1/stats) must already see the batch that served it counted.
-        if self._on_batch is not None:
-            try:
-                self._on_batch(len(batch), rows, bucket,
-                               [done - r.t_enqueue for r in batch], meta=meta)
-            except Exception:
-                pass  # a stats sink must never take down serving
-        off = 0
-        for r in batch:
-            n = r.x.shape[0]
-            if meta is not None:
-                # Stamped before set_result: a waiter woken by the result
-                # can always read the meta of the batch that served it.
-                r.future.batch_meta = meta
-            try:
-                r.future.set_result(y[off:off + n])
-            except BaseException:
-                pass  # cancelled/raced future; keep scattering the rest
-            off += n
+        with spans.span("repro.batch.scatter"):
+            if self._on_dispatch is not None:
+                try:
+                    self._on_dispatch(True, None)
+                except Exception:
+                    pass
+            done = self._clock()
+            # Stats are recorded BEFORE the futures resolve: a caller woken
+            # by its result (e.g. an HTTP client that immediately queries
+            # /v1/stats) must already see the batch that served it counted.
+            if self._on_batch is not None:
+                try:
+                    self._on_batch(len(batch), rows, bucket,
+                                   [done - r.t_enqueue for r in batch],
+                                   meta=meta)
+                except Exception:
+                    pass  # a stats sink must never take down serving
+            off = 0
+            for r in batch:
+                n = r.x.shape[0]
+                if meta is not None:
+                    # Stamped before set_result: a waiter woken by the
+                    # result can always read the meta of the batch that
+                    # served it.
+                    r.future.batch_meta = meta
+                try:
+                    r.future.set_result(y[off:off + n])
+                except BaseException:
+                    pass  # cancelled/raced future; keep scattering the rest
+                off += n
 
     def _try_dispatch(self, batch: list) -> Optional[BaseException]:
         """Dispatch with bounded transient retry; returns None on success
@@ -669,9 +710,10 @@ class MicroBatcher:
 
     def _run(self) -> None:
         while True:
-            batch = self._collect()
-            if batch is None:
+            first = self._first()  # the blocking wait: idle, not a span
+            if first is None:
                 return
-            if not batch:
-                continue  # everything collected had already expired
-            self.serve(batch)
+            with spans.span("repro.batch") as sp:
+                with spans.span("repro.batch.collect"):
+                    batch = self._gather(first, sp.id)
+                self._serve_collected(batch, sp)

@@ -41,12 +41,15 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from repro import spans
+
 from .batching import _fail
 from .reliability import DispatchError
 
 __all__ = ["FleetCoalescer"]
 
-# (device_out, stacked=[(slot, endpoint, batch, rows)...], bucket, t_launch)
+# (device_out, stacked=[(slot, endpoint, batch, rows)...], bucket, t_launch,
+#  span id of the launching round)
 _Pending = tuple
 
 
@@ -189,28 +192,39 @@ class FleetCoalescer:
 
     def _round(self) -> bool:
         """Collect/dispatch one coalescing round; True if any work moved."""
+        with spans.span("repro.fleet.round") as rnd:
+            return self._coalesce(rnd)
+
+    def _coalesce(self, rnd) -> bool:
+        """The round under its ``repro.fleet.round`` span ``rnd``."""
         stacked: List[tuple] = []  # (slot, ep, batch, rows)
         solo: List[tuple] = []
         def collect(skip=()):
-            for slot, ep in enumerate(self.members):
-                if slot in skip:
-                    continue
-                batch = ep.batcher.collect_nowait()
-                if not batch:
-                    continue
-                rows = sum(r.x.shape[0] for r in batch)
-                if ep.fleet_route():
-                    stacked.append((slot, ep, batch, rows))
-                else:
-                    solo.append((ep, batch))
+            with spans.span("repro.fleet.collect"):
+                for slot, ep in enumerate(self.members):
+                    if slot in skip:
+                        continue
+                    batch = ep.batcher.collect_nowait(rnd.id)
+                    if not batch:
+                        continue
+                    rows = sum(r.x.shape[0] for r in batch)
+                    if ep.fleet_route():
+                        stacked.append((slot, ep, batch, rows))
+                    else:
+                        solo.append((ep, batch))
 
         collect()
         if 2 <= len(stacked) < len(self.members) and self._hold_s > 0:
             # Partial stack: hold briefly for stragglers, then sweep once
             # more.  While a previous round is still on the device the
             # hold overlaps its compute and costs nothing.
-            time.sleep(self._hold_s)
+            with spans.span("repro.fleet.hold"):
+                time.sleep(self._hold_s)
             collect(skip={slot for slot, _, _, _ in stacked})
+        if rnd.id:
+            requests = (sum(len(b) for _, _, b, _ in stacked)
+                        + sum(len(b) for _, b in solo))
+            rnd.set(riders=len(stacked), requests=requests)
         if not stacked and not solo:
             # Idle: nothing can overlap with the in-flight round — force it
             # out so its callers are not held hostage to future traffic.
@@ -229,30 +243,34 @@ class FleetCoalescer:
             self._warmup()
         bucket = max(ep.policy.bucket_for(rows)
                      for _, ep, _, rows in stacked)
+        rnd.set(bucket=bucket)
         t0 = self._clock()
-        buf = self._staging_buffer(bucket)
         riders: List[tuple] = []
-        for slot, ep, batch, rows in stacked:
-            try:
-                off = 0
-                for r in batch:
-                    n = r.x.shape[0]
-                    buf[slot, off:off + n] = r.x
-                    off += n
-                buf[slot, rows:bucket] = 0
-            except Exception:
-                # Malformed rows (shape/dtype) fail alone on the member's
-                # own path (bisection isolates the poison request); the
-                # slot's half-written data is simply never scattered.
-                self._serve_solo(ep, batch)
-                continue
-            riders.append((slot, ep, batch, rows))
+        with spans.span("repro.fleet.assemble"):
+            buf = self._staging_buffer(bucket)
+            for slot, ep, batch, rows in stacked:
+                try:
+                    off = 0
+                    for r in batch:
+                        n = r.x.shape[0]
+                        buf[slot, off:off + n] = r.x
+                        off += n
+                    buf[slot, rows:bucket] = 0
+                except Exception:
+                    # Malformed rows (shape/dtype) fail alone on the
+                    # member's own path (bisection isolates the poison
+                    # request); the slot's half-written data is simply
+                    # never scattered.
+                    self._serve_solo(ep, batch)
+                    continue
+                riders.append((slot, ep, batch, rows))
         if not riders:
             self._finalize_pending()
             return True
         t1 = self._clock()
         try:
-            out = self.stack.predict_device(buf)  # async: NOT materialized
+            with spans.span("repro.fleet.launch"):
+                out = self.stack.predict_device(buf)  # async: NOT materialized
         except Exception:
             self.n_stack_fallbacks += 1
             for _, ep, batch, _ in riders:
@@ -266,7 +284,8 @@ class FleetCoalescer:
         # finalize the previous one — round t's materialization wait runs
         # while round t+1 computes, and round t+1's assembly already ran
         # while round t computed.
-        prev, self._pending = self._pending, (out, riders, bucket, t1)
+        prev, self._pending = self._pending, (out, riders, bucket, t1,
+                                              rnd.id)
         if prev is not None:
             self._finalize_round(prev)
         return True
@@ -279,39 +298,43 @@ class FleetCoalescer:
     def _finalize_round(self, pending: _Pending) -> None:
         """Materialize a launched round and scatter results to futures.
         Every rider's future resolves by the time this returns."""
-        out, riders, bucket, t_launch = pending
-        try:
-            y = np.asarray(out, np.int32)  # forces the device computation
-        except Exception:
-            # Deferred device failure: the whole round recomputes on the
-            # members' own paths (retry/bisection semantics included).
-            self.n_stack_fallbacks += 1
-            for _, ep, batch, _ in riders:
-                self._serve_solo(ep, batch)
-            return
-        self.device_s += self._clock() - t_launch
-        done = self._clock()
-        for slot, ep, batch, rows in riders:
-            meta = {"coalesced": True, "degraded": False,
-                    "number_format": ep.artifact.target.number_format}
+        out, riders, bucket, t_launch, launched_by = pending
+        with spans.span("repro.fleet.finalize", round=launched_by):
             try:
-                ep.stats.record_batch(len(batch), rows, bucket,
-                                      [done - r.t_enqueue for r in batch],
-                                      meta=meta)
+                # Forces the device computation.
+                with spans.span("repro.predict.sync", rows=out.size):
+                    y = np.asarray(out, np.int32)
             except Exception:
-                pass  # a stats sink must never take down serving
-            if ep.breaker is not None:
-                ep.breaker.record_success()
-            self.n_stacked_requests += len(batch)
-            row, off = y[slot], 0
-            for r in batch:
-                n = r.x.shape[0]
-                r.future.batch_meta = meta
+                # Deferred device failure: the whole round recomputes on
+                # the members' own paths (retry/bisection semantics
+                # included).
+                self.n_stack_fallbacks += 1
+                for _, ep, batch, _ in riders:
+                    self._serve_solo(ep, batch)
+                return
+            self.device_s += self._clock() - t_launch
+            done = self._clock()
+            for slot, ep, batch, rows in riders:
+                meta = {"coalesced": True, "degraded": False,
+                        "number_format": ep.artifact.target.number_format}
                 try:
-                    r.future.set_result(row[off:off + n])
-                except BaseException:
-                    pass  # cancelled/raced future; keep scattering
-                off += n
+                    ep.stats.record_batch(len(batch), rows, bucket,
+                                          [done - r.t_enqueue for r in batch],
+                                          meta=meta)
+                except Exception:
+                    pass  # a stats sink must never take down serving
+                if ep.breaker is not None:
+                    ep.breaker.record_success()
+                self.n_stacked_requests += len(batch)
+                row, off = y[slot], 0
+                for r in batch:
+                    n = r.x.shape[0]
+                    r.future.batch_meta = meta
+                    try:
+                        r.future.set_result(row[off:off + n])
+                    except BaseException:
+                        pass  # cancelled/raced future; keep scattering
+                    off += n
 
     def _run(self) -> None:
         while True:

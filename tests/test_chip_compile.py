@@ -14,6 +14,7 @@ imports this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +54,8 @@ def one_chip(topo):
 @pytest.fixture()
 def chip(monkeypatch, one_chip):
     """The kernel layer's platform decision answers "TPU"; returns a
-    compile function for one described chip."""
+    compile function for one described chip, which returns the compiled
+    program's text."""
     monkeypatch.setattr(tune, "on_tpu", lambda: True)
 
     def compile_for_chip(fn, *shapes):
@@ -61,6 +63,7 @@ def chip(monkeypatch, one_chip):
                 for s, d in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text, "no Mosaic kernel was compiled"
+        return text
 
     return compile_for_chip
 
@@ -137,6 +140,41 @@ def test_mlp_fleet_compiles(chip, uniform, bits, bucket):
             eb, dims, bits, b))
     chip(lambda x: ops.fxp_mlp_fleet(x, ws, bs, scheds, be=be, bm=bm),
          ((e, bucket, f1), fmt.dtype))
+
+
+def _instructions(text):
+    """Names of the compiled program's instructions (``%name = ...``)."""
+    return re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", text, re.M)
+
+
+def test_mlp_kernels_keep_the_names_a_device_trace_is_read_by(chip):
+    """A device trace names each TPU op by its HLO instruction; the MLP
+    megakernel's and the MLP fleet kernel's hold ``fxp_mlp_model`` and
+    ``fxp_mlp_fleet``, which readers of the trace match."""
+    fmt, bucket = FXP16, 8
+    ws = (_ints((F, H), fmt.dtype), _ints((H, C), fmt.dtype))
+    bs = (_ints((H,), fmt.dtype), _ints((C,), fmt.dtype))
+    sched = ((fmt.frac_bits, fmt, "pwl4"), (fmt.frac_bits, fmt, "none"))
+    dims = (F, H, C)
+    bm = tune.model_block_m(
+        "mlp", bucket, dims, 16,
+        vmem_bytes=lambda b: fxp_model.mlp_vmem_bytes(dims, 16, b))
+    text = chip(lambda x: ops.fxp_mlp_model(x, ws, bs, sched, bm=bm),
+                ((bucket, F), fmt.dtype))
+    assert any("fxp_mlp_model" in n for n in _instructions(text))
+    e, f1, c1 = 8, 42, 2
+    ws = (_ints((e, f1, H), fmt.dtype), _ints((e, H, c1), fmt.dtype))
+    bs = (_ints((e, H), fmt.dtype), _ints((e, c1), fmt.dtype))
+    other = ((fmt.frac_bits + 1, fmt, "exact"), (fmt.frac_bits, fmt, "none"))
+    scheds = tuple(sched if i % 2 else other for i in range(e))
+    dims = (f1, H, c1)
+    be, bm = tune.fleet_blocks(
+        "mlp", e, bucket, dims, 16, uniform=False,
+        vmem_bytes=lambda eb, b: fxp_model.mlp_fleet_vmem_bytes(
+            eb, dims, 16, b))
+    text = chip(lambda x: ops.fxp_mlp_fleet(x, ws, bs, scheds, be=be, bm=bm),
+                ((e, bucket, f1), fmt.dtype))
+    assert any("fxp_mlp_fleet" in n for n in _instructions(text))
 
 
 @pytest.mark.parametrize("bucket", BUCKETS)

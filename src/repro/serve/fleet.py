@@ -28,9 +28,14 @@ Per-endpoint semantics are preserved, not flattened:
 
 Overlap: the stacked dispatch is launched *asynchronously* (JAX async
 dispatch — ``FleetStack.predict_device`` returns an unmaterialized device
-array) and the round is finalized only after the *next* round's host
-assembly has been handed to the device, so batch assembly for round t+1
-runs concurrently with device compute of round t.
+array), so round t computes on the device while round t+1's first sweep
+runs.  Where round t+1 holds for stragglers, round t is finalized
+(materialized, scattered to its futures) at the start of that hold and
+only what is left of the hold is slept: the hold, idle time anyway, does
+the materialization, and round t's callers do not wait through round
+t+1's hold.  A round that does not hold (a full stack, a lone rider)
+launches first and finalizes round t after, so the sync overlaps its
+compute.
 """
 
 from __future__ import annotations
@@ -98,6 +103,7 @@ class FleetCoalescer:
         self.n_solo_batches = 0        # member batches served per-endpoint
         self.n_stack_fallbacks = 0     # stacked rounds re-served per member
         self.n_warmup_failures = 0     # warmup traces that raised
+        self.n_hold_finalizes = 0      # rounds finalized in the next hold
         self.assembly_s = 0.0          # host staging-buffer assembly time
         self.device_s = 0.0            # launch -> materialized outputs
         for ep in self.members:
@@ -136,6 +142,7 @@ class FleetCoalescer:
                 "solo_batches": self.n_solo_batches,
                 "stack_fallbacks": self.n_stack_fallbacks,
                 "warmup_failures": self.n_warmup_failures,
+                "hold_finalizes": self.n_hold_finalizes,
                 "staging_allocs": self.n_staging_allocs,
                 "assembly_s": self.assembly_s,
                 "device_s": self.device_s}
@@ -216,10 +223,16 @@ class FleetCoalescer:
         collect()
         if 2 <= len(stacked) < len(self.members) and self._hold_s > 0:
             # Partial stack: hold briefly for stragglers, then sweep once
-            # more.  While a previous round is still on the device the
-            # hold overlaps its compute and costs nothing.
-            with spans.span("repro.fleet.hold"):
-                time.sleep(self._hold_s)
+            # more.  The window runs from the end of the first sweep; the
+            # round in flight is finalized inside it, so its callers are
+            # answered now and not after this round's launch.
+            deadline = time.perf_counter() + self._hold_s
+            if self._finalize_pending(in_hold=True):
+                self.n_hold_finalizes += 1
+            left = deadline - time.perf_counter()
+            if left > 0:
+                with spans.span("repro.fleet.hold"):
+                    time.sleep(left)
             collect(skip={slot for slot, _, _, _ in stacked})
         if rnd.id:
             requests = (sum(len(b) for _, _, b, _ in stacked)
@@ -281,25 +294,29 @@ class FleetCoalescer:
         self.n_rounds += 1
         self.n_stacked_dispatches += 1
         # Pipeline depth 1: hand the new round to the device FIRST, then
-        # finalize the previous one — round t's materialization wait runs
-        # while round t+1 computes, and round t+1's assembly already ran
-        # while round t computed.
+        # finalize the previous one (unless the hold already did) — round
+        # t's materialization wait runs while round t+1 computes.
         prev, self._pending = self._pending, (out, riders, bucket, t1,
                                               rnd.id)
         if prev is not None:
             self._finalize_round(prev)
         return True
 
-    def _finalize_pending(self) -> None:
+    def _finalize_pending(self, in_hold: bool = False) -> bool:
+        """Finalize the round in flight, if any; True if there was one."""
         prev, self._pending = self._pending, None
         if prev is not None:
-            self._finalize_round(prev)
+            self._finalize_round(prev, in_hold)
+        return prev is not None
 
-    def _finalize_round(self, pending: _Pending) -> None:
+    def _finalize_round(self, pending: _Pending,
+                        in_hold: bool = False) -> None:
         """Materialize a launched round and scatter results to futures.
-        Every rider's future resolves by the time this returns."""
+        Every rider's future resolves by the time this returns.
+        ``in_hold``: run inside the next round's straggler hold."""
         out, riders, bucket, t_launch, launched_by = pending
-        with spans.span("repro.fleet.finalize", round=launched_by):
+        with spans.span("repro.fleet.finalize", round=launched_by,
+                        in_hold=int(in_hold)):
             try:
                 # Forces the device computation.
                 with spans.span("repro.predict.sync", rows=out.size):

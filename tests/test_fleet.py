@@ -13,6 +13,9 @@
 * zero-copy staging: the coalescer's buffer allocations plateau at two per
   bucket; the per-endpoint batch-1 fast path copies nothing;
 * degradation and circuit breaking honored per member under coalescing;
+* round order: a round that holds for stragglers finalizes the round in
+  flight inside its hold, before its own launch, and keeps its straggler
+  window; a round that does not hold launches first;
 * lifecycle: close resolves every future; ``get_or_stack`` dedupes.
 """
 
@@ -335,6 +338,161 @@ def test_breaker_member_probes_solo_then_rejoins(cache, fleet_arts, blobs):
             assert f.result(timeout=120)[0] == golden[i]
     finally:
         svc.close()
+
+
+# ---------------------------------------------------------------------------
+# round order: the in-flight round is finalized inside a straggler hold
+# ---------------------------------------------------------------------------
+class _LoggedStack:
+    """A fleet's stack that logs each launch into the test's event log."""
+
+    def __init__(self, stack, log):
+        self._stack, self._log = stack, log
+        self.n_models, self.n_features = stack.n_models, stack.n_features
+
+    def predict_device(self, buf):
+        self._log.append(("launch",))
+        return self._stack.predict_device(buf)
+
+
+class _Stepper:
+    """Runs the coalescer's rounds one at a time, when the test steps it;
+    every round runs once the coalescer is closing."""
+
+    def __init__(self, co):
+        self._co, self._round = co, co._round
+        self._permits = threading.Semaphore(0)
+        self._done = threading.Semaphore(0)
+        self._parked = threading.Event()
+        co._round = self._gated
+        assert self._parked.wait(30), "the coalescer never reached a round"
+
+    def _gated(self):
+        self._parked.set()
+        while not self._co._closed:
+            if self._permits.acquire(timeout=0.01):
+                break
+        try:
+            return self._round()
+        finally:
+            self._done.release()
+
+    def step(self):
+        """Run exactly one round; return once the next one is parked."""
+        self._parked.clear()
+        self._permits.release()
+        assert self._done.acquire(timeout=120)
+        assert self._parked.wait(30)
+
+
+@pytest.fixture()
+def stepped(cache, fleet_arts, blobs):
+    """A warmed fleet whose rounds the test steps, the shared event log
+    (launches and future completions), and a submit that logs its
+    future's completion under a tag."""
+    xte = blobs[2]
+    svc = _fleet_service(cache, fleet_arts)
+    co = next(iter(svc._fleets.values()))
+    for _ in range(50):
+        for f in [svc.endpoint(f"m{e}").submit(xte[e]) for e in range(E)]:
+            f.result(timeout=120)
+        if co.n_stacked_dispatches:
+            break
+    log = []
+    co.stack = _LoggedStack(co.stack, log)
+    stepper = _Stepper(co)
+
+    def submit(e, i, tag):
+        f = svc.endpoint(f"m{e}").submit(xte[i:i + 1])
+        f.add_done_callback(lambda _: log.append(("done", tag)))
+        return f
+
+    try:
+        yield svc, co, stepper, log, submit
+    finally:
+        svc.close()
+
+
+def _golden_check(fleet_arts, xte, served):
+    for e, i, f in served:
+        assert f.result(timeout=120)[0] == fleet_arts[e].predict(xte)[i]
+
+
+def test_held_round_finalizes_the_round_in_flight_before_its_launch(
+        stepped, fleet_arts, blobs):
+    svc, co, stepper, log, submit = stepped
+    first = [(e, e, submit(e, e, ("first", e))) for e in range(E)]
+    stepper.step()  # all members ride: launched, left in flight
+    assert log == [("launch",)]
+    assert not any(f.done() for _, _, f in first)
+    held = [(e, 5 + e, submit(e, 5 + e, ("held", e))) for e in (0, 1)]
+    stepper.step()  # two of three ride: the round holds
+    assert sorted(log[1:4]) == [("done", ("first", e)) for e in range(E)]
+    assert log[4:] == [("launch",)]
+    assert co.snapshot()["hold_finalizes"] == 1
+    assert not any(f.done() for _, _, f in held)
+    stepper.step()  # idle: the held round is forced out
+    _golden_check(fleet_arts, blobs[2], first + held)
+    assert co.snapshot()["hold_finalizes"] == 1
+
+
+@pytest.mark.parametrize("riders", [(0, 1, 2), (0,)], ids=["full", "lone"])
+def test_round_that_does_not_hold_finalizes_its_predecessor_after(
+        stepped, fleet_arts, blobs, riders):
+    svc, co, stepper, log, submit = stepped
+    first = [(e, e, submit(e, e, ("first", e))) for e in range(E)]
+    stepper.step()
+    nxt = [(e, 7 + e, submit(e, 7 + e, ("next", e))) for e in riders]
+    stepper.step()
+    if len(riders) == E:
+        # Launched first; the sync of the round before overlaps it.
+        assert log[1] == ("launch",)
+        assert sorted(log[2:]) == [("done", ("first", e)) for e in range(E)]
+    else:
+        # A lone rider is served on its own path, then the round before
+        # is finalized; nothing is launched.
+        assert log[1:] == [("done", ("next", 0))] + [
+            ("done", ("first", e)) for e in range(E)]
+    assert co.snapshot()["hold_finalizes"] == 0
+    stepper.step()
+    _golden_check(fleet_arts, blobs[2], first + nxt)
+
+
+def test_straggler_submitted_during_the_finalize_rides_the_held_round(
+        stepped, fleet_arts, blobs):
+    svc, co, stepper, log, submit = stepped
+    snap = co.snapshot()
+    stacked_before, solo_before = snap["stacked_requests"], snap["solo_batches"]
+    first = [(e, e, submit(e, e, ("first", e))) for e in range(E)]
+    stepper.step()
+    held = [(e, 5 + e, submit(e, 5 + e, ("held", e))) for e in (0, 1)]
+    late = []
+    # Runs on the coalescer thread while it finalizes the round in flight,
+    # after the held round's first sweep.
+    first[0][2].add_done_callback(
+        lambda _: late.append((2, 9, submit(2, 9, ("late", 2)))))
+    stepper.step()
+    assert len(late) == 1
+    assert log[-1] == ("launch",) and not late[0][2].done()
+    assert co.snapshot()["hold_finalizes"] == 1
+    stepper.step()  # idle: nothing was left queued for a third round
+    assert log[-1] == ("done", ("late", 2)) and log.count(("launch",)) == 2
+    assert late[0][2].batch_meta["coalesced"] is True
+    snap = co.snapshot()
+    assert snap["stacked_requests"] - stacked_before == E + E
+    assert snap["solo_batches"] == solo_before
+    _golden_check(fleet_arts, blobs[2], first + held + late)
+
+
+def test_close_resolves_the_round_in_flight_and_the_held_round(
+        stepped, fleet_arts, blobs):
+    svc, co, stepper, log, submit = stepped
+    first = [(e, e, submit(e, e, ("first", e))) for e in range(E)]
+    stepper.step()
+    held = [(e, 5 + e, submit(e, 5 + e, ("held", e))) for e in (0, 1)]
+    svc.close()  # the parked round runs, then the coalescer stops
+    assert all(f.done() for _, _, f in first + held)
+    _golden_check(fleet_arts, blobs[2], first + held)
 
 
 # ---------------------------------------------------------------------------

@@ -153,6 +153,17 @@ def traced(service, rows, tmp_path_factory):
                     if r[0] == "repro.fleet.hold"]
             if k >= 5 and held:
                 break
+        # Bursts of two members' requests, more than one round takes,
+        # leave a round in flight while the next one holds; repeat until
+        # one round finalized it inside its hold and then slept.
+        for k in range(40):
+            futs = [service.submit(n, rows[i]) for i in range(8)
+                    for n in ("m0", "m1")]
+            for f in futs:
+                f.result(timeout=120)
+            submitted += len(futs)
+            if _finalized_and_slept(spans.collected(t0, float("inf"))):
+                break
         _serve(service, rows, FLEET, 0)
         submitted += 3
     # Spans open when the trace stopped (an idle round's sweep) close
@@ -165,6 +176,14 @@ def traced(service, rows, tmp_path_factory):
     assert paths, "the profiler wrote no trace"
     return {"records": recs, "submitted": submitted, "xplane": paths[0],
             "t0": t0, "t1": t1}
+
+
+def _finalized_and_slept(recs):
+    """Rounds that finalized the round in flight inside their hold and
+    then slept what was left of it."""
+    slept = {r[5] for r in recs if r[0] == "repro.fleet.hold"}
+    return {r[5] for r in recs if r[0] == "repro.fleet.finalize"
+            and _ints(r)["in_hold"]} & slept
 
 
 def _by_id(recs):
@@ -235,6 +254,21 @@ def test_fleet_rounds_record_their_spans_with_parents(traced):
     for r in recs:
         if r[0] == "repro.fleet.finalize":
             assert _ints(r)["round"] in rounds
+    # A round that holds first finalizes the round in flight, as a child
+    # of its own, and then sleeps what is left of the hold: the finalize
+    # ends before the hold starts, and no hold contains a finalize.
+    finalizes = [r for r in recs if r[0] == "repro.fleet.finalize"]
+    holds = [r for r in recs if r[0] == "repro.fleet.hold"]
+    in_hold = [r for r in finalizes if _ints(r)["in_hold"] == 1]
+    assert _finalized_and_slept(recs)
+    held = {r[5] for r in holds} | {r[5] for r in in_hold}
+    assert all(by_id[i][0] == "repro.fleet.round" for i in held)
+    for f in finalizes:
+        assert _ints(f)["in_hold"] == (f[5] in held)
+        for h in holds:
+            if h[5] == f[5]:
+                assert f[2] <= h[1]
+            assert not (h[1] <= f[1] and f[2] <= h[2])
     # The rounds that took this fleet's requests ran on one thread, the
     # coalescer's.
     took = {_ints(r)["taker"] for r in recs if r[0] == "repro.request.queue"}

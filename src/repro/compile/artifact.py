@@ -260,8 +260,9 @@ class CompiledArtifact:
         """Paper-style resource report for this artifact.
 
         Always includes the memory model and the per-tensor number formats
-        (the QuantPlan table for calibrated targets, the single global
-        format otherwise).  ``model_bytes`` is computed from the *actual
+        (the QuantPlan table for calibrated targets, with
+        ``chain_frac_bits`` for a calibrated RBF SVM's distance, exponent
+        and kernel value; the single global format otherwise).  ``model_bytes`` is computed from the *actual
         quantized tensors* (per-tensor container widths), not a float-size
         estimate.  Given an evaluation batch ``x``, adds the observed
         saturation/underflow counts (paper §V-A); given labels ``y`` as
@@ -303,6 +304,10 @@ class CompiledArtifact:
                 path: repr(self.quant_plan.fmt(path))
                 for path in self.quant_plan.paths()}
             rep["calibration_ranges"] = dict(self.quant_plan.ranges)
+            if "chain_frac_bits" in self.extras:
+                # fractional bits of values that are no plan path, such as
+                # the RBF SVM's int32 squared distance
+                rep["chain_frac_bits"] = dict(self.extras["chain_frac_bits"])
         elif self.target.is_quantized:
             rep["formats"] = {"*": repr(self.target.fmt)}
         else:

@@ -196,7 +196,7 @@ def _stack_svm(artifacts) -> Callable[[np.ndarray], Any]:
     dual = jnp.stack([jnp.asarray(s["dual"]) for s in specs])
     icept = jnp.stack([jnp.asarray(s["b"]) for s in specs])
     params = tuple((s["fmt"], s["out_fmt"], s["qgamma"], s["qcoef0"],
-                    s["degree"], s["dec_shift"]) for s in specs)
+                    s["degree"], s["dec_shift"], s["chain"]) for s in specs)
     qstack = _quantizer([s["fmt"] for s in specs], len(specs))
 
     @jax.jit

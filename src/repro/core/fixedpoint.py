@@ -50,6 +50,8 @@ __all__ = [
     "qmatmul_with_stats",
     "requantize",
     "rshift_round_saturate",
+    "acc_scale_consts",
+    "scale_acc",
     "quantize_with_stats",
     "qexp",
     "qsigmoid",
@@ -212,14 +214,17 @@ def one_q(fmt: FxpFormat) -> int:
     return min(1 << fmt.frac_bits, fmt.qmax)
 
 
-def exp_poly_consts(fmt: FxpFormat) -> Tuple[int, Tuple[int, int, int, int]]:
-    """Per-format integer constants of :func:`qexp`: ``(log2e_q, (c0..c3))``.
+def exp_poly_consts(fmt: FxpFormat, frac_bits: Optional[int] = None
+                    ) -> Tuple[int, Tuple[int, int, int, int]]:
+    """Integer constants of :func:`qexp`: ``(log2e_q, (c0..c3))`` at
+    ``frac_bits`` fractional bits (default: the format's own).
 
     Shared between the traced implementation below and the C emitter
     (:mod:`repro.emit`), so both quantize the polynomial identically.
     """
-    log2e_q = int(round(_LOG2_E * fmt.scale))
-    coeffs = tuple(int(round(c * fmt.scale)) for c in _EXP2_COEFFS)
+    scale = fmt.scale if frac_bits is None else float(2 ** frac_bits)
+    log2e_q = int(round(_LOG2_E * scale))
+    coeffs = tuple(int(round(c * scale)) for c in _EXP2_COEFFS)
     return log2e_q, coeffs
 
 
@@ -301,6 +306,56 @@ def requantize(acc: jax.Array, shift: int, fmt: FxpFormat) -> jax.Array:
     if shift < 0:
         raise ValueError(f"requantize shift must be >= 0, got {shift}")
     return _saturate(_rshift_round(acc, shift), fmt)
+
+
+# Widest integer multiplier of :func:`scale_acc` (15 bits of the constant).
+_MULT_MAX = (1 << 15) - 1
+_INT32_MAX = (1 << 31) - 1
+
+
+def acc_scale_consts(c: float, acc_frac: int,
+                     fmt: FxpFormat) -> Tuple[int, int, int, int]:
+    """Integer constants ``(pre, cap, mult, post)`` of :func:`scale_acc`:
+    a non-negative int32 accumulator at ``acc_frac`` fractional bits times
+    the real constant ``c >= 0``, into ``fmt``.
+
+    ``c * 2^(m_out - acc_frac)`` is written ``mult / 2^(pre + post)`` with
+    ``mult`` a 15-bit integer.  ``post = 31 - total_bits`` (less, only if
+    the constant is too large for it) keeps the product of every result
+    the output can hold inside int32; ``pre`` drops the accumulator's low
+    bits that a 15-bit multiplier cannot use; ``cap = (2^31 - 1) // mult``
+    bounds the product, and for 8- and 16-bit outputs any capped product
+    already saturates ``fmt``, so the cap changes no result (a 32-bit
+    output has ``post = 0`` and tops out within ``mult`` of its maximum).
+    Computed once, on the host: every backend and the emitted C then apply
+    the same integers.
+    """
+    if c < 0:
+        raise ValueError(f"acc_scale_consts takes c >= 0, got {c}")
+    r = float(c) * 2.0 ** (fmt.frac_bits - acc_frac)
+    post = max(0, 31 - fmt.total_bits)
+    if r == 0.0:
+        return 0, _INT32_MAX, 0, post
+    while post > 0 and r * 2.0 ** post > _MULT_MAX:
+        post -= 1
+    pre = 0
+    while pre < 30 and r * 2.0 ** (post + pre + 1) <= _MULT_MAX:
+        pre += 1
+    mult = min(_MULT_MAX, int(round(r * 2.0 ** (post + pre))))
+    cap = _INT32_MAX // mult if mult else _INT32_MAX
+    return pre, cap, mult, post
+
+
+def scale_acc(acc: jax.Array, consts: Tuple[int, int, int, int],
+              fmt: FxpFormat) -> jax.Array:
+    """``saturate(round(min(round(acc / 2^pre), cap) * mult / 2^post))``:
+    a non-negative int32 accumulator times a constant, in int32 alone
+    (a kernel body has no int64).  ``consts`` from
+    :func:`acc_scale_consts`; both roundings are :func:`_rshift_round`'s.
+    """
+    pre, cap, mult, post = consts
+    a = jnp.minimum(_rshift_round(acc, pre), jnp.asarray(cap, acc.dtype))
+    return requantize(a * jnp.asarray(mult, acc.dtype), post, fmt)
 
 
 def rshift_round_saturate(acc: jax.Array, fmt: FxpFormat) -> jax.Array:
@@ -400,41 +455,53 @@ _EXP2_COEFFS = (0.9999936, 0.6964313, 0.2243984, 0.0792043)
 _LOG2_E = 1.4426950408889634
 
 
-def qexp(x: jax.Array, fmt: FxpFormat) -> jax.Array:
+def qexp(x: jax.Array, fmt: FxpFormat,
+         out_fmt: Optional[FxpFormat] = None) -> jax.Array:
     """Fixed-point exp(x): exp(x) = 2^(x*log2e) = 2^k * 2^f, f in [0,1).
 
-    Implemented entirely in Qn.m integer ops (one widening multiply per
-    polynomial term), mirroring libfixmath's exp.  Saturates on overflow,
-    flushes to zero for k below -m (true underflow, which the paper counts).
+    ``x`` is in ``fmt``; the result lands in ``out_fmt`` (default ``fmt``).
+    The polynomial runs at ``m = max(m_in, m_out)`` fractional bits, so an
+    output with more fractional bits than the input keeps them; with equal
+    formats this is the single-format exp.  Implemented entirely in integer
+    ops on the input's wide dtype (one widening multiply per polynomial
+    term), mirroring libfixmath's exp.  Saturates on overflow, flushes to
+    zero below the output's resolution (true underflow, which the paper
+    counts).
     """
-    m = fmt.frac_bits
+    out_fmt = fmt if out_fmt is None else out_fmt
+    m = max(fmt.frac_bits, out_fmt.frac_bits)
+    tb = out_fmt.total_bits
     wide = fmt.wide_dtype
-    log2e_q, (c0, c1, c2, c3) = exp_poly_consts(fmt)
-    y = _rshift_round(x.astype(wide) * log2e_q, m)  # y = x*log2e in Qn.m (wide)
+    log2e_q, (c0, c1, c2, c3) = exp_poly_consts(fmt, m)
+    # y = x*log2e in Q.m (wide)
+    y = _rshift_round(x.astype(wide) * log2e_q, fmt.frac_bits)
     k = y >> m  # floor(y): arithmetic shift == floor for two's complement
     f = y - (k << m)  # fractional part in [0, 2^m)
-    # Horner in Qn.m on the wide dtype.
+    # Horner in Q.m on the wide dtype.
     acc = jnp.full_like(f, c3)
     acc = _rshift_round(acc * f, m) + c2
     acc = _rshift_round(acc * f, m) + c1
-    acc = _rshift_round(acc * f, m) + c0  # ~2^f in Qn.m, in [2^m, 2^(m+1))
-    # Scale by 2^k: left shift when k>=0 (with saturation), right when k<0.
+    acc = _rshift_round(acc * f, m) + c0  # ~2^f in Q.m, in [2^m, 2^(m+1))
+    # Scale by 2^k into the output's m_out fractional bits: left shift when
+    # the net exponent is >= 0 (with saturation), right when it is < 0.
     k_i32 = k.astype(jnp.int32)
-    max_shift = fmt.total_bits  # beyond this always saturates / flushes
+    if out_fmt.frac_bits != m:
+        k_i32 = k_i32 + jnp.int32(out_fmt.frac_bits - m)
+    max_shift = tb  # beyond this always saturates / flushes
     k_clamped = jnp.minimum(jnp.maximum(k_i32, jnp.int32(-max_shift)),
                             jnp.int32(max_shift))
     zero = jnp.zeros_like(k_clamped)
     pos = jnp.maximum(k_clamped, zero).astype(wide)
     neg = jnp.maximum(-k_clamped, zero).astype(wide)
-    shifted_up = acc << jnp.minimum(pos, fmt.total_bits - 1).astype(wide)
+    shifted_up = acc << jnp.minimum(pos, tb - 1).astype(wide)
     # Detect overflow of the left shift on the wide dtype.
-    overflowed = (shifted_up >> jnp.minimum(pos, fmt.total_bits - 1).astype(wide)) != acc
-    up = jnp.where(overflowed, jnp.asarray(fmt.qmax, wide), shifted_up)
-    down = _rshift_round(acc, 0) >> jnp.minimum(neg, fmt.total_bits + m).astype(wide)
+    overflowed = (shifted_up >> jnp.minimum(pos, tb - 1).astype(wide)) != acc
+    up = jnp.where(overflowed, jnp.asarray(out_fmt.qmax, wide), shifted_up)
+    down = _rshift_round(acc, 0) >> jnp.minimum(neg, tb + m).astype(wide)
     out = jnp.where(k_clamped >= 0, up, down)
-    # Saturate positive overflow (k too large).
-    out = jnp.where(k_i32 >= fmt.int_bits, jnp.asarray(fmt.qmax, wide), out)
-    return _saturate(out, fmt)
+    # Saturate positive overflow (2^k * acc past the output's range).
+    out = jnp.where(k_i32 >= tb - 1 - m, jnp.asarray(out_fmt.qmax, wide), out)
+    return _saturate(out, out_fmt)
 
 
 def qrecip(x: jax.Array, fmt: FxpFormat) -> jax.Array:

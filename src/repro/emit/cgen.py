@@ -121,7 +121,6 @@ class _P:
         self.m = fmt.frac_bits
         self.tb = fmt.total_bits
         self.wb = _WIDE_BITS[fmt.total_bits]
-        self.ib = fmt.int_bits
         self.qmin = fmt.qmin
         self.qmax = fmt.qmax
         self.ctype = CTYPES[fmt.total_bits]
@@ -208,17 +207,20 @@ static inline int32_t fxp_qdiv(int32_t a, int32_t b, int m, int32_t qmin,
   return fxp_sat(q_trunc, qmin, qmax);
 }
 
-/* qexp: exp(x) = 2^(x*log2e) = 2^k * 2^f with a cubic 2^f polynomial; the
+/* qexp: exp(x) = 2^(x*log2e) = 2^k * 2^f with a cubic 2^f polynomial at m
+ * fractional bits; x has m_in of them, the result m + kd in a tb-bit
+ * container (qmin, qmax); wb is the input's wide width.  The
  * overflow-detecting left shift deliberately wraps at the wide width,
  * exactly like the traced op */
-static inline int32_t fxp_qexp(int32_t x, int m, int tb, int wb, int ib,
-                               int32_t qmin, int32_t qmax, int64_t log2e_q,
-                               int64_t c0, int64_t c1, int64_t c2,
-                               int64_t c3) {
-  int64_t y = fxp_rshr(fxp_wrap(fxp_mul_wrap((int64_t)x, log2e_q), wb), m);
+static inline int32_t fxp_qexp(int32_t x, int m_in, int m, int kd, int tb,
+                               int wb, int32_t qmin, int32_t qmax,
+                               int64_t log2e_q, int64_t c0, int64_t c1,
+                               int64_t c2, int64_t c3) {
+  int64_t y = fxp_rshr(fxp_wrap(fxp_mul_wrap((int64_t)x, log2e_q), wb),
+                       m_in);
   int64_t k = y >> m;
   int64_t f = y - fxp_shl(k, m);
-  int32_t k_i32 = (int32_t)fxp_wrap(k, 32);
+  int32_t k_i32 = (int32_t)fxp_wrap(k, 32) + kd;
   int32_t k_cl = (k_i32 < -tb) ? -tb : ((k_i32 > tb) ? tb : k_i32);
   int pos = (k_cl > 0) ? k_cl : 0;
   int neg = (k_cl < 0) ? -k_cl : 0;
@@ -232,7 +234,7 @@ static inline int32_t fxp_qexp(int32_t x, int m, int tb, int wb, int ib,
   shifted_up = fxp_wrap(fxp_shl(acc, s_up), wb);
   up = ((shifted_up >> s_up) != acc) ? (int64_t)qmax : shifted_up;
   out = (k_cl >= 0) ? up : (acc >> s_dn);
-  if (k_i32 >= ib) out = (int64_t)qmax;
+  if (k_i32 >= tb - 1 - m) out = (int64_t)qmax;
   return fxp_sat(out, qmin, qmax);
 }
 
@@ -251,11 +253,11 @@ static inline int32_t fxp_qpow(int32_t x, int p, int m, int32_t one,
 
 /* sigmoid variants — constants quantized host-side, passed as integers */
 static inline int32_t fxp_qsig_exact(int32_t x, int m, int tb, int wb,
-                                     int ib, int32_t qmin, int32_t qmax,
+                                     int32_t qmin, int32_t qmax,
                                      int32_t one, int64_t log2e_q, int64_t c0,
                                      int64_t c1, int64_t c2, int64_t c3) {
   int64_t na = (x < 0) ? (int64_t)x : -(int64_t)x;
-  int32_t e = fxp_qexp(fxp_sat(na, qmin, qmax), m, tb, wb, ib, qmin, qmax,
+  int32_t e = fxp_qexp(fxp_sat(na, qmin, qmax), m, m, 0, tb, wb, qmin, qmax,
                        log2e_q, c0, c1, c2, c3);
   int32_t denom = fxp_sat((int64_t)one + (int64_t)e, qmin, qmax);
   int32_t pos = fxp_qdiv(one, denom, m, qmin, qmax);
@@ -314,7 +316,7 @@ def _act_call(var: str, act: str, p: _P) -> str:
     if act == "exact":
         log2e, (c0, c1, c2, c3) = fxp.exp_poly_consts(fmt)
         one = fxp.one_q(fmt)
-        return (f"fxp_qsig_exact({var}, {p.m}, {p.tb}, {p.wb}, {p.ib}, "
+        return (f"fxp_qsig_exact({var}, {p.m}, {p.tb}, {p.wb}, "
                 f"{_ci(p.qmin)}, {_ci(p.qmax)}, {_ci(one)}, {_ci(log2e)}, "
                 f"{_ci(c0)}, {_ci(c1)}, {_ci(c2)}, {_ci(c3)})")
     if act == "pwl2":
@@ -414,14 +416,28 @@ def _emit_svm(spec: Dict[str, Any], lines: List[str],
     ns, nf = sv.shape
     nc = dual.shape[1]
     kernel = spec["kernel"]
+    chain = spec.get("chain")
     dec_shift = spec["dec_shift"]
-    qgamma, qcoef0 = _ci(spec["qgamma"]), _ci(spec["qcoef0"])
 
     arrays.append(_carray("EMB_SV", sv, CTYPES[spec_ctbits(sv)]))
     arrays.append(_carray("EMB_DUAL", dual.T, CTYPES[spec_ctbits(dual)]))
     arrays.append(_carray("EMB_ICEPT", icept, CTYPES[spec_ctbits(icept)]))
 
-    if kernel == "rbf":
+    if chain is not None:
+        lines.append(f"""\
+/* sum(q^2) exactly, wrapped to int32: the squared distance's norm terms
+ * (the traced chain sums in int64 and wraps once) */
+static int32_t emb_qsq_norm(const {p.ctype} *v, int n) {{
+  uint64_t acc = 0u;
+  int i;
+  for (i = 0; i < n; ++i) {{
+    int64_t q = (int64_t)v[i];
+    acc += (uint64_t)(q * q);
+  }}
+  return (int32_t)fxp_wrap(fxp_u2s(acc), 32);
+}}
+""")
+    elif kernel == "rbf":
         lines.append(f"""\
 /* sum(q^2) at the wide width, one rounded shift + saturation at the end
  * (products wrap at the wide dtype, the sum accumulates mod 2^64 — the
@@ -435,7 +451,9 @@ static int32_t emb_qsq_norm(const {p.ctype} *v, int n) {{
   }}
   return fxp_requant(fxp_u2s(acc), {p.m}, {_ci(p.qmin)}, {_ci(p.qmax)});
 }}
-
+""")
+    if kernel == "rbf":
+        lines.append(f"""\
 /* |sv_s|^2, computed once on first use (RAM, not flash) */
 static int32_t emb_sv2[{ns}];
 static int emb_sv2_ready = 0;
@@ -455,39 +473,19 @@ static int emb_sv2_ready = 0;
     emb_sv2_ready = 1;
   }}
   x2 = emb_qsq_norm(x, {nf});""")
-    lines.append(f"  /* kernel row: x . sv_s, shift {p.m} */")
+    lines.append(f"  /* kernel row: x . sv_s */")
     lines.append(f"  for (s = 0; s < {ns}; ++s) {{")
     lines.append(f"    uint64_t acc = 0u;")
-    lines.append(f"    int32_t dot, t;")
+    lines.append(f"    int32_t t;" if chain is not None
+                 else f"    int32_t dot, t;")
     lines.append(f"    for (k = 0; k < {nf}; ++k) {{")
     lines.append(f"      acc += (uint64_t)((int64_t)x[k]"
                  f" * (int64_t)EMB_SV[s][k]);")
     lines.append(f"    }}")
-    lines.append(f"    dot = fxp_requant(fxp_wrap(fxp_u2s(acc), {p.wb}), "
-                 f"{p.m}, {_ci(p.qmin)}, {_ci(p.qmax)});")
-    if kernel == "poly":
-        lines.append(f"    /* k = (gamma * dot + coef0) ** degree */")
-        lines.append(f"    t = fxp_sat((int64_t)fxp_qmul(dot, {qgamma}, "
-                     f"{p.m}, {_ci(p.qmin)}, {_ci(p.qmax)}) + "
-                     f"(int64_t){qcoef0}, {_ci(p.qmin)}, {_ci(p.qmax)});")
-        lines.append(f"    kv[s] = fxp_qpow(t, {int(spec['degree'])}, {p.m}, "
-                     f"{_ci(fxp.one_q(spec['fmt']))}, {_ci(p.qmin)}, "
-                     f"{_ci(p.qmax)});")
+    if chain is not None:
+        lines.extend(_chain_lines(chain))
     else:
-        log2e, (c0, c1, c2, c3) = fxp.exp_poly_consts(spec["fmt"])
-        lines.append(f"    /* k = exp(-gamma * (x2 - 2 dot + sv2)) */")
-        lines.append(f"    t = fxp_sat((int64_t)dot + (int64_t)dot, "
-                     f"{_ci(p.qmin)}, {_ci(p.qmax)});")
-        lines.append(f"    t = fxp_sat((int64_t)x2 - (int64_t)t, "
-                     f"{_ci(p.qmin)}, {_ci(p.qmax)});")
-        lines.append(f"    t = fxp_sat((int64_t)t + (int64_t)emb_sv2[s], "
-                     f"{_ci(p.qmin)}, {_ci(p.qmax)});")
-        lines.append(f"    t = fxp_sat(-(int64_t)fxp_qmul(t, {qgamma}, "
-                     f"{p.m}, {_ci(p.qmin)}, {_ci(p.qmax)}), "
-                     f"{_ci(p.qmin)}, {_ci(p.qmax)});")
-        lines.append(f"    kv[s] = fxp_qexp(t, {p.m}, {p.tb}, {p.wb}, "
-                     f"{p.ib}, {_ci(p.qmin)}, {_ci(p.qmax)}, {_ci(log2e)}, "
-                     f"{_ci(c0)}, {_ci(c1)}, {_ci(c2)}, {_ci(c3)});")
+        lines.extend(_single_format_lines(spec, p))
     lines.append("  }")
     lines.append(f"  /* decision: kv @ dual + intercept, shift {dec_shift} */")
     lines.append(f"  for (c = 0; c < {nc}; ++c) {{")
@@ -504,6 +502,68 @@ static int emb_sv2_ready = 0;
     lines.append("  }")
     lines.append(f"  return fxp_argmax(out, {nc});")
     lines.append("}")
+
+
+def _single_format_lines(spec: Dict[str, Any], p: _P) -> List[str]:
+    """The kernel value from the x·sv accumulator ``acc`` in the one
+    format of a fixed target (or a calibrated poly SVM)."""
+    qgamma, qcoef0 = _ci(spec["qgamma"]), _ci(spec["qcoef0"])
+    lines = [f"    dot = fxp_requant(fxp_wrap(fxp_u2s(acc), {p.wb}), "
+             f"{p.m}, {_ci(p.qmin)}, {_ci(p.qmax)});"]
+    if spec["kernel"] == "poly":
+        lines.append(f"    /* k = (gamma * dot + coef0) ** degree */")
+        lines.append(f"    t = fxp_sat((int64_t)fxp_qmul(dot, {qgamma}, "
+                     f"{p.m}, {_ci(p.qmin)}, {_ci(p.qmax)}) + "
+                     f"(int64_t){qcoef0}, {_ci(p.qmin)}, {_ci(p.qmax)});")
+        lines.append(f"    kv[s] = fxp_qpow(t, {int(spec['degree'])}, {p.m}, "
+                     f"{_ci(fxp.one_q(spec['fmt']))}, {_ci(p.qmin)}, "
+                     f"{_ci(p.qmax)});")
+        return lines
+    log2e, (c0, c1, c2, c3) = fxp.exp_poly_consts(spec["fmt"])
+    lines.append(f"    /* k = exp(-gamma * (x2 - 2 dot + sv2)) */")
+    lines.append(f"    t = fxp_sat((int64_t)dot + (int64_t)dot, "
+                 f"{_ci(p.qmin)}, {_ci(p.qmax)});")
+    lines.append(f"    t = fxp_sat((int64_t)x2 - (int64_t)t, "
+                 f"{_ci(p.qmin)}, {_ci(p.qmax)});")
+    lines.append(f"    t = fxp_sat((int64_t)t + (int64_t)emb_sv2[s], "
+                 f"{_ci(p.qmin)}, {_ci(p.qmax)});")
+    lines.append(f"    t = fxp_sat(-(int64_t)fxp_qmul(t, {qgamma}, "
+                 f"{p.m}, {_ci(p.qmin)}, {_ci(p.qmax)}), "
+                 f"{_ci(p.qmin)}, {_ci(p.qmax)});")
+    lines.append(f"    kv[s] = fxp_qexp(t, {p.m}, {p.m}, 0, {p.tb}, "
+                 f"{p.wb}, {_ci(p.qmin)}, {_ci(p.qmax)}, {_ci(log2e)}, "
+                 f"{_ci(c0)}, {_ci(c1)}, {_ci(c2)}, {_ci(c3)});")
+    return lines
+
+
+def _chain_lines(chain) -> List[str]:
+    """A calibrated rbf's kernel value from the x·sv accumulator ``acc``:
+    ``rbf_chain_ref`` in C (int32 distance, ``scale_acc``, two-format
+    ``qexp``)."""
+    e, kf = _P(chain.exp_fmt), _P(chain.kernel_fmt)
+    pre, cap, mult, post = chain.scale
+    m = max(e.m, kf.m)
+    log2e, (c0, c1, c2, c3) = fxp.exp_poly_consts(chain.exp_fmt, m)
+    return [
+        "    /* d2 = x2 - 2 dot + sv2, exact mod 2^32; a wrapped negative",
+        "     * is the largest distance */",
+        "    t = (int32_t)fxp_wrap(fxp_u2s((uint64_t)(int64_t)x2 - 2u * acc"
+        " + (uint64_t)(int64_t)emb_sv2[s]), 32);",
+        f"    if (t < 0) t = {_ci((1 << 31) - 1)};",
+        f"    /* exponent = gamma * d2: shift {pre}, cap {cap}, times "
+        f"{mult}, shift {post} */",
+        "    {",
+        f"      int64_t a = fxp_rshr((int64_t)t, {pre});",
+        f"      if (a > {_ci(cap)}) a = {_ci(cap)};",
+        f"      t = fxp_requant(a * {_ci(mult)}, {post}, {_ci(e.qmin)}, "
+        f"{_ci(e.qmax)});",
+        "    }",
+        "    /* k = exp(-exponent) into the kernel value's format */",
+        f"    kv[s] = fxp_qexp(fxp_sat(-(int64_t)t, {_ci(e.qmin)}, "
+        f"{_ci(e.qmax)}), {e.m}, {m}, {kf.m - m}, {kf.tb}, {e.wb}, "
+        f"{_ci(kf.qmin)}, {_ci(kf.qmax)}, {_ci(log2e)}, {_ci(c0)}, "
+        f"{_ci(c1)}, {_ci(c2)}, {_ci(c3)});",
+    ]
 
 
 def _emit_tree(spec: Dict[str, Any], lines: List[str],

@@ -23,7 +23,10 @@ The kernels here collapse the entire forward pass into a single
   (x·svᵀ plus the poly/rbf elementwise algebra, including the in-kernel
   squared norms for rbf) and the fused decision matmul + intercept, in one
   body.  Collapses the previous 2-dispatch pallas path
-  (``fxp_qmatmul`` + ``fxp_layer``) to 1.
+  (``fxp_qmatmul`` + ``fxp_layer``) to 1.  A calibrated rbf passes an
+  :class:`RbfChain`: the squared distance stays in the int32 accumulator
+  and the exponent and kernel value take formats of their own (see
+  :mod:`repro.compile.lowerings.svm`).
 
 Accumulator contract: identical to :mod:`.fxp_layer` — int32 MXU
 accumulation, bit-exact vs the wide-accumulating oracle whenever the true
@@ -55,7 +58,7 @@ from __future__ import annotations
 import functools
 import os
 from itertools import chain
-from typing import Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +74,7 @@ from . import tune
 from .tune import _VMEM_BUDGET
 
 __all__ = ["fxp_mlp_model_pallas", "fxp_svm_model_pallas", "LayerSchedule",
+           "RbfChain",
            "mlp_fits_vmem", "svm_fits_vmem", "vmem_budget", "SVM_KERNELS",
            "fxp_mlp_fleet_pallas", "fxp_svm_fleet_pallas", "FleetSchedules",
            "SvmFleetParams", "mlp_fleet_fits_vmem", "svm_fleet_fits_vmem",
@@ -80,8 +84,23 @@ __all__ = ["fxp_mlp_model_pallas", "fxp_svm_model_pallas", "LayerSchedule",
 LayerSchedule = Tuple[Tuple[int, FxpFormat, str], ...]
 # One LayerSchedule per stacked model (fleet kernels).
 FleetSchedules = Tuple[LayerSchedule, ...]
-# One per stacked SVM: (fmt, out_fmt, qgamma, qcoef0, degree, dec_shift).
-SvmFleetParams = Tuple[Tuple[FxpFormat, FxpFormat, int, int, int, int], ...]
+
+
+class RbfChain(NamedTuple):
+    """The calibrated RBF kernel's formats past the int32 squared distance:
+    ``exponent = scale_acc(d2, scale, exp_fmt)`` (``scale`` from
+    :func:`repro.core.fixedpoint.acc_scale_consts` for gamma), then
+    ``k = qexp(-exponent, exp_fmt, kernel_fmt)``."""
+
+    exp_fmt: FxpFormat
+    kernel_fmt: FxpFormat
+    scale: Tuple[int, int, int, int]
+
+
+# One per stacked SVM: (fmt, out_fmt, qgamma, qcoef0, degree, dec_shift,
+# chain), chain None for the single-format arithmetic.
+SvmFleetParams = Tuple[Tuple[FxpFormat, FxpFormat, int, int, int, int,
+                             Optional[RbfChain]], ...]
 
 SVM_KERNELS = ("poly", "rbf")
 
@@ -288,9 +307,24 @@ def _qsq_norm(qv, fmt: FxpFormat, vfmt: FxpFormat):
     return fixedpoint.rshift_round_saturate(acc, vfmt)
 
 
+def _sq_dist(qx, qsv, dot):
+    """``|x - sv|^2`` as int32 from the raw x·svᵀ accumulator: the row
+    norms and ``dot`` summed with int32's wraparound, so the result is the
+    exact distance modulo 2^32 in any order of summation (the reference
+    sums in int64 and wraps once).  The planner keeps calibrated distances
+    inside int32; past it, a wrapped negative reads as the largest int32."""
+    def norm(v):
+        w = v.astype(jnp.int32)
+        return jnp.sum(w * w, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    d2 = norm(qx) - (dot + dot) + jnp.swapaxes(norm(qsv), -1, -2)
+    return jnp.where(d2 < 0, jnp.int32(jnp.iinfo(jnp.int32).max), d2)
+
+
 def _svm_decision(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
                   out_fmt: FxpFormat, qgamma: int, qcoef0: int, degree: int,
-                  dec_shift: int, batched: bool):
+                  dec_shift: int, batched: bool,
+                  chain: Optional[RbfChain] = None):
     """The whole decision function on (bm, F) values -> (bm, C), or on
     model-stacked (be, bm, F) -> (be, bm, C) when ``batched`` (the model
     axis rides as a dot_general batch dimension; models never mix).
@@ -299,7 +333,7 @@ def _svm_decision(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
     One spelling of the algebra for the single-model kernel, the uniform
     fleet and the fleet's per-model branches — one bit-identity contract.
     """
-    vfmt, vout = vpu_format(fmt), vpu_format(out_fmt)
+    vfmt = vpu_format(fmt)
     if batched:
         sv_dims = (((2,), (2,)), ((0,), (0,)))
         dual_dims = (((2,), (1,)), ((0,), (0,)))
@@ -307,9 +341,17 @@ def _svm_decision(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
         sv_dims = (((1,), (1,)), ((), ()))
         dual_dims = (((1,), (0,)), ((), ()))
     # x . sv^T without materializing the transpose: contract the shared
-    # feature axis.  Integer dot == fxp_qmatmul's accumulate, then the
-    # single-format requantize (input/sv/kernel share one plan group).
+    # feature axis.  Integer dot == fxp_qmatmul's accumulate.
     dot = mxu_dot(qx, qsv, sv_dims)
+    if chain is not None:
+        # Calibrated rbf: the distance never leaves int32; the exponent
+        # and the kernel value take their own formats.
+        vexp = vpu_format(chain.exp_fmt)
+        arg = fixedpoint.scale_acc(_sq_dist(qx, qsv, dot), chain.scale, vexp)
+        k = fixedpoint.qexp(fixedpoint.qneg(arg, vexp), vexp,
+                            vpu_format(chain.kernel_fmt))
+        return _svm_vote(k, dual, icept, dual_dims, dec_shift, out_fmt)
+    # Single format: the requantize into the input/sv/kernel format.
     dot = fixedpoint.requantize(dot, fmt.frac_bits, vfmt)
     g = jnp.asarray(qgamma, fmt.dtype)
     if kind == "poly":
@@ -324,8 +366,13 @@ def _svm_decision(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
             sv2, vfmt)
         arg = fixedpoint.qneg(fixedpoint.qmul(d2, g, vfmt), vfmt)
         k = fixedpoint.qexp(arg, vfmt)
-    # Decision stage: the fused-layer epilogue (k @ dual, cross-format
-    # shift, saturating intercept add) still inside the same kernel body.
+    return _svm_vote(k, dual, icept, dual_dims, dec_shift, out_fmt)
+
+
+def _svm_vote(k, dual, icept, dual_dims, dec_shift: int, out_fmt: FxpFormat):
+    """Decision stage: the fused-layer epilogue (k @ dual, cross-format
+    shift, saturating intercept add) still inside the same kernel body."""
+    vout = vpu_format(out_fmt)
     acc = mxu_dot(k, dual, dual_dims)
     out = fixedpoint.requantize(acc, dec_shift, vout)
     out = fixedpoint.qadd(out, icept, vout)
@@ -334,20 +381,21 @@ def _svm_decision(qx, qsv, dual, icept, *, kind: str, fmt: FxpFormat,
 
 def _svm_kernel(x_ref, sv_ref, dual_ref, icept_ref, o_ref, *, kind: str,
                 fmt: FxpFormat, out_fmt: FxpFormat, qgamma: int, qcoef0: int,
-                degree: int, dec_shift: int):
+                degree: int, dec_shift: int, chain: Optional[RbfChain]):
     o_ref[...] = _svm_decision(
         x_ref[...], sv_ref[...], dual_ref[...], icept_ref[...], kind=kind,
         fmt=fmt, out_fmt=out_fmt, qgamma=qgamma, qcoef0=qcoef0,
-        degree=degree, dec_shift=dec_shift, batched=False)
+        degree=degree, dec_shift=dec_shift, batched=False, chain=chain)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "kind", "fmt", "out_fmt", "qgamma", "qcoef0", "degree", "dec_shift",
-    "bm", "interpret"))
+    "chain", "bm", "interpret"))
 def fxp_svm_model_pallas(qx: jax.Array, sv: jax.Array, dual: jax.Array,
                          icept: jax.Array, kind: str, fmt: FxpFormat,
                          out_fmt: FxpFormat, qgamma: int, qcoef0: int,
-                         degree: int, dec_shift: int, bm: int = 128,
+                         degree: int, dec_shift: int,
+                         chain: Optional[RbfChain] = None, bm: int = 128,
                          interpret: bool = False) -> jax.Array:
     """The whole kernel-SVM decision function in one ``pallas_call``.
 
@@ -355,7 +403,8 @@ def fxp_svm_model_pallas(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     icept: (C,) — support vectors/duals ride whole, batch blocked by ``bm``.
     ``qgamma``/``qcoef0`` are the *quantized integer* constants (static, so
     they trace as kernel immediates); ``dec_shift`` is the decision stage's
-    cross-format requantization (``m_k + m_dual - m_out``).
+    cross-format requantization (``m_k + m_dual - m_out``); ``chain`` (rbf
+    only) replaces the single-format distance and exp.
     """
     if kind not in SVM_KERNELS:
         raise KeyError(f"kind must be one of {SVM_KERNELS}")
@@ -367,7 +416,8 @@ def fxp_svm_model_pallas(qx: jax.Array, sv: jax.Array, dual: jax.Array,
 
     kernel = functools.partial(
         _svm_kernel, kind=kind, fmt=fmt, out_fmt=out_fmt, qgamma=qgamma,
-        qcoef0=qcoef0, degree=int(degree), dec_shift=int(dec_shift))
+        qcoef0=qcoef0, degree=int(degree), dec_shift=int(dec_shift),
+        chain=chain)
     rows = index_map(lambda i: (i, 0))
     whole = index_map(lambda i: (0, 0))
     return pl.pallas_call(
@@ -542,21 +592,23 @@ def _svm_fleet_kernel(x_ref, sv_ref, dual_ref, icept_ref, o_ref, *,
                       kind: str, params: SvmFleetParams, be: int):
     uniq, indices = _uniq_branches(params)
     if len(uniq) == 1:
-        fmt, out_fmt, qgamma, qcoef0, degree, dec_shift = uniq[0]
+        fmt, out_fmt, qgamma, qcoef0, degree, dec_shift, rbf = uniq[0]
         o_ref[...] = _svm_decision(
             x_ref[...], sv_ref[...], dual_ref[...], icept_ref[...],
             kind=kind, fmt=fmt, out_fmt=out_fmt, qgamma=qgamma,
-            qcoef0=qcoef0, degree=degree, dec_shift=dec_shift, batched=True)
+            qcoef0=qcoef0, degree=degree, dec_shift=dec_shift, batched=True,
+            chain=rbf)
         return
 
     def _branch(p):
-        fmt, out_fmt, qgamma, qcoef0, degree, dec_shift = p
+        fmt, out_fmt, qgamma, qcoef0, degree, dec_shift, rbf = p
 
         def run(qx, qsv, dual, icept):
             return _svm_decision(qx, qsv, dual, icept, kind=kind, fmt=fmt,
                                  out_fmt=out_fmt, qgamma=qgamma,
                                  qcoef0=qcoef0, degree=degree,
-                                 dec_shift=dec_shift, batched=False)
+                                 dec_shift=dec_shift, batched=False,
+                                 chain=rbf)
         return run
 
     out = jax.lax.switch(
@@ -574,8 +626,8 @@ def fxp_svm_fleet_pallas(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     """E stacked kernel-SVM decision functions in one ``pallas_call``.
 
     qx: (E, M, F); sv: (E, S, F); dual: (E, S, C); icept: (E, C); ``params``
-    holds model e's static (fmt, out_fmt, qgamma, qcoef0, degree, dec_shift)
-    at index e.  Heterogeneous params require ``be == 1``.
+    holds model e's static (fmt, out_fmt, qgamma, qcoef0, degree, dec_shift,
+    chain) at index e.  Heterogeneous params require ``be == 1``.
     """
     if kind not in SVM_KERNELS:
         raise KeyError(f"kind must be one of {SVM_KERNELS}")

@@ -125,15 +125,15 @@ def _timed_runner(make_call):
     return run
 
 
-def _tuning_operands(m: int, k: int, n: int, fmt: FxpFormat,
+def _tuning_operands(m: int, k: int, n: int, dtype,
                      blocks: tune.Blocks):
     """Zero operands shaped exactly as the kernel would see them for these
     blocks — the same bucket-then-pad policy as the real dispatch path, kept
     in one place so the tuner times what the kernel will actually run."""
     bm, bn, bk = blocks
     mb = tune.batch_bucket(m, cap=1 << 30)
-    za = jnp.zeros((-(-mb // bm) * bm, -(-k // bk) * bk), fmt.dtype)
-    zb = jnp.zeros((za.shape[1], -(-n // bn) * bn), fmt.dtype)
+    za = jnp.zeros((-(-mb // bm) * bm, -(-k // bk) * bk), dtype)
+    zb = jnp.zeros((za.shape[1], -(-n // bn) * bn), dtype)
     return za, zb
 
 
@@ -151,7 +151,10 @@ def _matmul_tuning(kind: str, m: int, k: int, n: int, fmt: FxpFormat,
 def fxp_qmatmul(a: jax.Array, b: jax.Array, fmt: FxpFormat,
                 impl: str = "pallas",
                 blocks: Optional[tune.Blocks] = None) -> jax.Array:
-    """Qn.m matmul.  a: (M, K), b: (K, N) in fmt.dtype -> (M, N).
+    """Qn.m matmul.  a: (M, K), b: (K, N) -> (M, N) in ``fmt``; the
+    operands are in ``fmt.dtype``, or narrower for a wider accumulator
+    format (a 32-bit ``fmt`` with no fractional bits returns the raw int32
+    accumulator).
 
     ``blocks`` overrides the autotuned (bm, bn, bk); pass it to reproduce a
     fixed blocking (e.g. the historical 128/128/256 defaults in benchmarks).
@@ -162,7 +165,7 @@ def fxp_qmatmul(a: jax.Array, b: jax.Array, fmt: FxpFormat,
     (m, k), n = a.shape, b.shape[1]
     if blocks is None:
         def make_call(blk):
-            za, zb = _tuning_operands(m, k, n, fmt, blk)
+            za, zb = _tuning_operands(m, k, n, a.dtype, blk)
             return fxp_qmatmul_pallas(za, zb, fmt, bm=blk[0], bn=blk[1],
                                       bk=blk[2])
 
@@ -196,7 +199,7 @@ def fxp_layer(a: jax.Array, w: jax.Array, bias: jax.Array, fmt: FxpFormat,
     (m, k), n = a.shape, w.shape[1]
     if blocks is None:
         def make_call(blk):
-            za, zw = _tuning_operands(m, k, n, fmt, blk)
+            za, zw = _tuning_operands(m, k, n, a.dtype, blk)
             zb = jnp.zeros((zw.shape[1],), fmt.dtype)
             return fxp_layer_pallas(za, zw, zb, fmt, activation, shift=shift,
                                     bm=blk[0], bn=blk[1], bk=blk[2])
@@ -285,20 +288,21 @@ def fxp_svm_model(qx: jax.Array, sv: jax.Array, dual: jax.Array,
                   icept: jax.Array, kind: str, fmt: FxpFormat,
                   out_fmt: FxpFormat, qgamma: int, qcoef0: int, degree: int,
                   dec_shift: int, impl: str = "pallas",
-                  bm: Optional[int] = None) -> jax.Array:
+                  bm: Optional[int] = None,
+                  chain: Optional[fxp_model.RbfChain] = None) -> jax.Array:
     """The whole kernel-SVM decision function in ONE kernel dispatch:
     x·svᵀ, the poly/rbf elementwise algebra, and the decision matmul +
     intercept (see :mod:`repro.kernels.fxp_model`).  ``sv`` is the
     un-transposed (S, F) matrix; ``qgamma``/``qcoef0`` the quantized
-    integer constants.  Collapses the previous fxp_qmatmul + fxp_layer
-    pallas path (2 dispatches) to 1; bit-identical to it and to
-    :func:`repro.kernels.ref.fxp_svm_model_ref`.
+    integer constants; ``chain`` the calibrated rbf's formats.  Collapses
+    the previous fxp_qmatmul + fxp_layer pallas path (2 dispatches) to 1;
+    bit-identical to it and to :func:`repro.kernels.ref.fxp_svm_model_ref`.
     """
     _tick()
     if impl in ("xla", "ref"):
         return ref_ops.fxp_svm_model_ref(qx, sv, dual, icept, kind, fmt,
                                          out_fmt, qgamma, qcoef0, degree,
-                                         dec_shift)
+                                         dec_shift, chain)
     m, n_feat = qx.shape
     n_sv, n_cls = dual.shape
     bits = fmt.total_bits
@@ -312,7 +316,7 @@ def fxp_svm_model(qx: jax.Array, sv: jax.Array, dual: jax.Array,
                               qx.dtype), sv, dual, icept, chip)
                 return fxp_svm_model_pallas(zx, zsv, zd, zi, kind, fmt,
                                             out_fmt, qgamma, qcoef0, degree,
-                                            dec_shift, bm=blk)
+                                            dec_shift, chain, bm=blk)
 
             runner = _timed_runner(make_call)
         bm = tune.model_block_m(
@@ -323,7 +327,7 @@ def fxp_svm_model(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     xp, m0 = _pad_axis(qx, 0, bm)
     xp, svp, dp, ip = _padded_svm_operands(xp, sv, dual, icept, chip)
     out = fxp_svm_model_pallas(xp, svp, dp, ip, kind, fmt, out_fmt, qgamma,
-                               qcoef0, degree, dec_shift, bm=bm,
+                               qcoef0, degree, dec_shift, chain, bm=bm,
                                interpret=not chip)
     return out[:m0, :n_cls]
 
@@ -420,7 +424,7 @@ def fxp_svm_fleet(qx: jax.Array, sv: jax.Array, dual: jax.Array,
 
     qx: (E, M, F); sv: (E, S, F); dual: (E, S, C); icept: (E, C);
     ``params[e]`` = model e's static (fmt, out_fmt, qgamma, qcoef0, degree,
-    dec_shift) tuple.  Slot e is bit-identical to model e's own
+    dec_shift, chain) tuple.  Slot e is bit-identical to model e's own
     :func:`fxp_svm_model` call.
     """
     _tick()

@@ -19,6 +19,7 @@ from repro.core.trees import TreeArrays, predict_oblivious
 __all__ = ["fxp_qmatmul_ref", "fxp_layer_ref", "fxp_layer_ref_with_stats",
            "fxp_mlp_model_ref", "fxp_svm_model_ref", "fxp_mlp_fleet_ref",
            "fxp_svm_fleet_ref", "pwl_activation_ref", "tree_ensemble_ref",
+           "rbf_chain_ref",
            "flash_attention_ref"]
 
 
@@ -80,10 +81,39 @@ def fxp_mlp_model_ref(x: jax.Array, weights, biases, schedule) -> jax.Array:
     return h
 
 
+def rbf_chain_ref(qx: jax.Array, sv: jax.Array, chain,
+                  dot: jax.Array | None = None) -> jax.Array:
+    """The calibrated rbf kernel values (M, S) in ``chain.kernel_fmt``.
+
+    The squared distance ``x2 - 2 dot + sv2`` is summed in int64 and
+    wrapped to int32 once (exact whenever it fits int32, and equal mod 2^32
+    to the kernels' int32 sums of the same terms), a wrapped negative read
+    as the largest int32; ``scale_acc`` carries it into the exponent's
+    format and ``qexp`` into the kernel value's.  ``dot`` is the raw
+    x·svᵀ accumulator where a kernel computed it (only its value mod 2^32
+    counts).
+    """
+    if dot is None:
+        dot = jax.lax.dot_general(qx.astype(jnp.int64), sv.astype(jnp.int64),
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.int64)
+
+    def norm(v):
+        w = v.astype(jnp.int64)
+        return jnp.sum(w * w, -1)
+
+    d2 = (norm(qx)[:, None] - 2 * dot.astype(jnp.int64)
+          + norm(sv)[None, :]).astype(jnp.int32)
+    d2 = jnp.where(d2 < 0, jnp.int32(np.iinfo(np.int32).max), d2)
+    arg = fxp.scale_acc(d2, chain.scale, chain.exp_fmt)
+    return fxp.qexp(fxp.qneg(arg, chain.exp_fmt), chain.exp_fmt,
+                    chain.kernel_fmt)
+
+
 def fxp_svm_model_ref(qx: jax.Array, sv: jax.Array, dual: jax.Array,
                       icept: jax.Array, kind: str, fmt: fxp.FxpFormat,
                       out_fmt: fxp.FxpFormat, qgamma: int, qcoef0: int,
-                      degree: int, dec_shift: int) -> jax.Array:
+                      degree: int, dec_shift: int, chain=None) -> jax.Array:
     """Whole-model kernel-SVM oracle: the chained decision function.
 
     Mirrors the per-stage lowering exactly — ``fxp_qmatmul_ref`` for
@@ -91,8 +121,12 @@ def fxp_svm_model_ref(qx: jax.Array, sv: jax.Array, dual: jax.Array,
     oracle for the decision stage — so the megakernel's single dispatch has
     a composed-from-parts oracle to be bit-identical to.  ``sv`` is the
     un-transposed (S, F) support-vector matrix; ``qgamma``/``qcoef0`` are
-    the quantized integer constants.
+    the quantized integer constants; ``chain`` (a calibrated rbf's
+    :class:`repro.kernels.fxp_model.RbfChain`) selects :func:`rbf_chain_ref`.
     """
+    if chain is not None:
+        k = rbf_chain_ref(qx, sv, chain)
+        return fxp_layer_ref(k, dual, icept, out_fmt, "none", dec_shift)
     dot = fxp_qmatmul_ref(qx, sv.T, fmt)
     g = jnp.asarray(qgamma, fmt.dtype)
     if kind == "poly":
@@ -130,7 +164,8 @@ def fxp_mlp_fleet_ref(x: jax.Array, weights, biases, schedules) -> jax.Array:
 def fxp_svm_fleet_ref(qx: jax.Array, sv: jax.Array, dual: jax.Array,
                       icept: jax.Array, kind: str, params) -> jax.Array:
     """Fleet-stacked kernel-SVM oracle (see :func:`fxp_mlp_fleet_ref`);
-    ``params[e]`` = (fmt, out_fmt, qgamma, qcoef0, degree, dec_shift)."""
+    ``params[e]`` = (fmt, out_fmt, qgamma, qcoef0, degree, dec_shift,
+    chain)."""
     return jnp.stack([
         fxp_svm_model_ref(qx[e], sv[e], dual[e], icept[e], kind, *params[e])
         for e in range(qx.shape[0])])

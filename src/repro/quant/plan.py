@@ -21,6 +21,10 @@ Planning constraints (enforced by :func:`plan_formats`):
   (int32 on the Pallas MXU, ``fmt.wide_dtype`` on the reference path), with
   2x headroom for quantization noise — this is what keeps
   ``ref == xla == pallas`` bit-identical for calibrated targets;
+* **int32 accumulators** — a value every backend keeps in int32 and never
+  stores in the container (the RBF SVM's squared distance, at ``fa + fb``
+  fractional bits) must fit int32 with the same headroom, whatever the
+  container width;
 * **shift** — ``f_out <= f_a + f_b`` so the requantization shift
   (:func:`repro.core.fixedpoint.requantize`) is non-negative.
 
@@ -60,13 +64,18 @@ class Calibration:
     * ``matmuls`` — ``(a_path, b_path, out_path)`` triples for every integer
       matmul the lowering emits (drives the accumulator-width constraint);
     * ``acc_ranges`` — ``out_path`` -> max absolute value of the float
-      accumulator (pre-shift, pre-bias) for that matmul.
+      accumulator (pre-shift, pre-bias) for that matmul, and the same for
+      each int32 accumulator by its name;
+    * ``int32_accs`` — ``(a_path, b_path, name)`` triples for integer
+      products of ``a`` and ``b`` terms that stay in an int32 accumulator
+      at ``fa + fb`` fractional bits (``name`` has no container format).
     """
 
     ranges: Mapping[str, float]
     groups: Tuple[Tuple[str, ...], ...] = ()
     matmuls: Tuple[Tuple[str, str, str], ...] = ()
     acc_ranges: Mapping[str, float] = dataclasses.field(default_factory=dict)
+    int32_accs: Tuple[Tuple[str, str, str], ...] = ()
 
 
 def choose_frac_bits(amax: float, total_bits: int) -> int:
@@ -207,23 +216,30 @@ def plan_formats(calib: Calibration, total_bits: int) -> QuantPlan:
                 changed = True
         return changed
 
+    def fit_acc(a: str, b: str, acc: str, budget: int) -> bool:
+        # int accumulator magnitude ~ |acc_float| * 2^(fa+fb); keep it
+        # (with headroom) inside a signed accumulator of ``budget`` bits.
+        acc_amax = abs(float(calib.acc_ranges.get(acc, 0.0)))
+        changed = False
+        while (frac[a] + frac[b] > 0
+               and acc_amax * _ACC_HEADROOM * (1 << (frac[a] + frac[b]))
+               > (1 << budget) - 1):
+            victim = a if frac[a] >= frac[b] else b
+            frac[victim] -= 1
+            changed = True
+        return changed
+
     budget = _acc_budget(total_bits)
     for _ in range(32 * max(1, len(frac))):  # decreasing ints: converges fast
         changed = False
         for group in calib.groups:
             changed |= lower_to(group, min(frac[p] for p in group))
         for a, b, out in calib.matmuls:
-            # int accumulator magnitude ~ |acc_float| * 2^(fa+fb); keep it
-            # (with headroom) inside the narrowest backend accumulator.
-            acc_amax = abs(float(calib.acc_ranges.get(out, 0.0)))
-            while (frac[a] + frac[b] > 0
-                   and acc_amax * _ACC_HEADROOM * (1 << (frac[a] + frac[b]))
-                   > (1 << budget) - 1):
-                victim = a if frac[a] >= frac[b] else b
-                frac[victim] -= 1
-                changed = True
+            changed |= fit_acc(a, b, out, budget)
             # the requantize shift fa + fb - f_out must be >= 0
             changed |= lower_to([out], frac[a] + frac[b])
+        for a, b, acc in calib.int32_accs:
+            changed |= fit_acc(a, b, acc, 31)
         if not changed:
             break
     return QuantPlan(

@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.fixedpoint import FXP8, FXP16
+from repro.core.fixedpoint import FXP8, FXP16, FxpFormat, acc_scale_consts
 from repro.kernels import fxp_model, ops, tune
 
 # D6 (HAR): 561 features, 6 classes; the benchmark's MLP hidden width and
@@ -104,6 +104,32 @@ def test_svm_megakernel_compiles(chip, kind, bits, bucket):
          ((bucket, F), fmt.dtype))
 
 
+def _rbf_chain(bits):
+    """A calibrated rbf chain as the D6 plan has it (input Q.6, exponent
+    Q.12, kernel value Q1.14 at 16 bits; Q.1, Q.4, Q1.6 at 8)."""
+    m_x, m_e, m_k = (6, 12, 14) if bits == 16 else (1, 4, 6)
+    exp_fmt = FxpFormat(bits, m_e)
+    return FxpFormat(bits, m_x), fxp_model.RbfChain(
+        exp_fmt, FxpFormat(bits, m_k),
+        acc_scale_consts(6.0e-5, 2 * m_x, exp_fmt))
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+@pytest.mark.parametrize("bits", [8, 16])
+def test_rbf_chain_megakernel_compiles(chip, bits, bucket):
+    """The calibrated rbf: int32 distance, ``scale_acc`` and the
+    two-format ``qexp`` inside the megakernel."""
+    fmt, chain = _rbf_chain(bits)
+    sv, dual = _ints((S, F), fmt.dtype), _ints((S, C), fmt.dtype)
+    icept = _ints((C,), fmt.dtype)
+    bm = tune.model_block_m(
+        "svm-rbf", bucket, (F, S, C), bits,
+        vmem_bytes=lambda b: fxp_model.svm_vmem_bytes(S, F, C, bits, b))
+    chip(lambda x: ops.fxp_svm_model(x, sv, dual, icept, "rbf", fmt, fmt,
+                                     0, 0, 3, 10, bm=bm, chain=chain),
+         ((bucket, F), fmt.dtype))
+
+
 @pytest.mark.parametrize("bucket", BUCKETS)
 @pytest.mark.parametrize("bits", [8, 16])
 @pytest.mark.parametrize("op", ["layer", "qmatmul"])
@@ -177,6 +203,23 @@ def test_mlp_kernels_keep_the_names_a_device_trace_is_read_by(chip):
     assert any("fxp_mlp_fleet" in n for n in _instructions(text))
 
 
+def test_svm_kernel_keeps_the_name_a_device_trace_is_read_by(chip):
+    """The calibrated rbf megakernel's instruction holds ``fxp_svm_model``,
+    which the benchmark's SVM roofline reader matches."""
+    fmt, chain = _rbf_chain(16)
+    bucket = 8
+    sv, dual = _ints((S, F), fmt.dtype), _ints((S, C), fmt.dtype)
+    icept = _ints((C,), fmt.dtype)
+    bm = tune.model_block_m(
+        "svm-rbf", bucket, (F, S, C), 16,
+        vmem_bytes=lambda b: fxp_model.svm_vmem_bytes(S, F, C, 16, b))
+    text = chip(lambda x: ops.fxp_svm_model(x, sv, dual, icept, "rbf", fmt,
+                                            fmt, 0, 0, 3, 10, bm=bm,
+                                            chain=chain),
+                ((bucket, F), fmt.dtype))
+    assert any("fxp_svm_model" in n for n in _instructions(text))
+
+
 @pytest.mark.parametrize("bucket", BUCKETS)
 @pytest.mark.parametrize("bits", [8, 16])
 @pytest.mark.parametrize("uniform", [True, False], ids=["uniform", "mixed"])
@@ -186,8 +229,31 @@ def test_svm_fleet_compiles(chip, uniform, bits, bucket):
     fmt, e = FORMATS[bits], 8
     sv, dual = _ints((e, S, F), fmt.dtype), _ints((e, S, C), fmt.dtype)
     icept = _ints((e, C), fmt.dtype)
-    base = (fmt, fmt, 3, 1, 3, fmt.frac_bits)
-    other = (fmt, fmt, 5, 1, 3, fmt.frac_bits)
+    base = (fmt, fmt, 3, 1, 3, fmt.frac_bits, None)
+    other = (fmt, fmt, 5, 1, 3, fmt.frac_bits, None)
+    params = tuple(base if uniform or i % 2 else other for i in range(e))
+    be, bm = tune.fleet_blocks(
+        "svm-rbf", e, bucket, (F, S, C), bits, uniform=uniform,
+        vmem_bytes=lambda eb, b: fxp_model.svm_fleet_vmem_bytes(
+            eb, S, F, C, bits, b))
+    chip(lambda x: ops.fxp_svm_fleet(x, sv, dual, icept, "rbf", params,
+                                     be=be, bm=bm),
+         ((e, bucket, F), fmt.dtype))
+
+
+@pytest.mark.parametrize("bucket", [1, 256])
+@pytest.mark.parametrize("bits", [8, 16])
+@pytest.mark.parametrize("uniform", [True, False], ids=["uniform", "mixed"])
+def test_rbf_chain_fleet_compiles(chip, uniform, bits, bucket):
+    """8 stacked calibrated rbf SVMs: the chain batched over the model
+    axis, and a ``lax.switch`` whose branches mix the chain with the
+    single-format arithmetic."""
+    fmt, chain = _rbf_chain(bits)
+    e = 8
+    sv, dual = _ints((e, S, F), fmt.dtype), _ints((e, S, C), fmt.dtype)
+    icept = _ints((e, C), fmt.dtype)
+    base = (fmt, fmt, 0, 0, 3, 10, chain)
+    other = (fmt, fmt, 3, 1, 3, fmt.frac_bits, None)
     params = tuple(base if uniform or i % 2 else other for i in range(e))
     be, bm = tune.fleet_blocks(
         "svm-rbf", e, bucket, (F, S, C), bits, uniform=uniform,

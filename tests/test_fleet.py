@@ -4,7 +4,8 @@
   megakernel MLP/logistic/SVM yes; trees, xla backends, mixed containers no);
 * FleetStack slot bit-identity: slot ``e`` of the stacked dispatch equals
   member ``e``'s own ``predict`` — shared rows and per-slot rows, for the
-  heterogeneous (calibrated auto16) MLP path and the SVM path;
+  heterogeneous (calibrated auto16) MLP path and the SVM path, calibrated
+  rbf chains included;
 * ONE dispatch per stacked forward (fresh-stack trace, the megakernel gate);
 * ``enable_fleet`` golden bit-identity with mixed model kinds registered —
   incompatible endpoints (tree, xla) keep their own workers;
@@ -170,6 +171,30 @@ def test_stack_svm_slot_identity(blobs):
     out = stack_fleet(arts).predict(xte[:12])
     for e, art in enumerate(arts):
         np.testing.assert_array_equal(out[e], art.predict(xte[:12]))
+
+
+@pytest.mark.parametrize("uniform", [True, False], ids=["uniform", "mixed"])
+@pytest.mark.parametrize("fmt", ["auto16", "auto8"])
+def test_stack_calibrated_rbf_slot_identity(blobs, fmt, uniform):
+    """Calibrated rbf members carry their chain of formats into the stack:
+    one plan for every member (the batched kernel), or a calibrated member
+    beside a single-format fxp one (the ``lax.switch`` branches mix the
+    chain with the single-format arithmetic)."""
+    xtr, ytr, xte, _ = blobs
+    model = train_kernel_svm(xtr, ytr, C, kernel="rbf", n_prototypes=16,
+                             epochs=3, seed=0)
+    bits = fmt[len("auto"):]
+    second = (Target(number_format=fmt, backend="pallas") if uniform
+              else Target(number_format=f"fxp{bits}", backend="pallas"))
+    arts = [compile(model, Target(number_format=fmt, backend="pallas"),
+                    calibration=xtr),
+            compile(model, second, calibration=xtr if uniform else None)]
+    assert arts[0].extras["emit_spec"]["chain"] is not None
+    assert fleet_signature(arts[0]) == fleet_signature(arts[1])
+    x = np.concatenate([xte[:12], xte[:4] * 5.0])
+    out = stack_fleet(arts).predict(x)
+    for e, art in enumerate(arts):
+        np.testing.assert_array_equal(out[e], art.predict(x))
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,7 @@
   path with correct parent links; every request's queue wait names the
   batch or round that took it; the profiler's own trace holds the same
   scoped spans; spans keep the real clock under an injected batcher clock;
+  a calibrated rbf SVM endpoint's dispatch records the same program spans;
 * the ring keeps at most ``CAPACITY`` records and counts what it drops.
 """
 
@@ -23,7 +24,7 @@ import pytest
 
 from repro import spans
 from repro.compile import Target
-from repro.models import train_mlp
+from repro.models import train_kernel_svm, train_mlp
 from repro.serve import BatchingPolicy, InferenceService, MicroBatcher
 
 F, C = 8, 3
@@ -304,6 +305,38 @@ def test_profiler_trace_holds_the_scoped_spans(traced):
     assert "repro.request.queue" not in names  # memory only
     for name, n in ring.items():
         assert names[name] >= n, name
+
+
+def test_svm_endpoint_dispatch_records_the_program_spans(rows, tmp_path):
+    """A calibrated rbf SVM endpoint adds no host step of its own: its
+    dispatch runs inside ``repro.batch`` > ``.dispatch`` >
+    ``repro.predict.call`` / ``.sync``, like any endpoint's."""
+    y = np.arange(len(rows), dtype=np.int32) % C
+    model = train_kernel_svm(rows, y, C, kernel="rbf", n_prototypes=12,
+                             epochs=2, seed=0)
+    svc = InferenceService()
+    try:
+        svc.register("svm", model, Target(number_format="auto16",
+                                          backend="pallas"),
+                     policy=BatchingPolicy(max_batch=4, max_wait_ms=2),
+                     calibration=rows)
+        assert svc.endpoint("svm").artifact.kernel_strategy == "megakernel"
+        svc.predict("svm", rows[:8])  # warm the bucket
+        spans.clear()
+        with jax.profiler.trace(str(tmp_path)):
+            svc.predict("svm", rows[:8])
+            svc.submit("svm", rows[0]).result(timeout=120)
+        recs = spans.collected(float("-inf"), float("inf"))
+    finally:
+        svc.close()
+    by_id = _by_id(recs)
+    chain = collections.Counter(
+        (r[0], by_id[r[5]][0], by_id[by_id[r[5]][5]][0]) for r in recs
+        if r[0].startswith("repro.predict.") and r[5] in by_id)
+    assert chain[("repro.predict.call", "repro.batch.dispatch",
+                  "repro.batch")] >= 2
+    assert chain[("repro.predict.sync", "repro.batch.dispatch",
+                  "repro.batch")] >= 2
 
 
 def test_spans_keep_the_real_clock_under_an_injected_one(tmp_path):

@@ -175,11 +175,17 @@ class CompiledArtifact:
         return _specialize_mesh(self, mesh, strategy)
 
     # -- inference -----------------------------------------------------------
-    def predict(self, x: np.ndarray) -> np.ndarray:
-        rows = len(x)
-        with spans.span("repro.predict.call", rows=rows):
+    def predict_device(self, x: np.ndarray) -> Any:
+        """One dispatch; returns the outputs unforced: a device array that
+        JAX may still be computing (or a host array, where the program
+        makes one).  The caller forces it with ``np.asarray``."""
+        with spans.span("repro.predict.call", rows=len(x)):
             out, _ = self._predict(x)
-        with spans.span("repro.predict.sync", rows=rows):
+        return out
+
+    def predict(self, x: np.ndarray) -> np.ndarray:
+        out = self.predict_device(x)
+        with spans.span("repro.predict.sync", rows=len(x)):
             return np.asarray(out, np.int32)
 
     def predict_with_stats(self, x: np.ndarray) -> Tuple[np.ndarray, Dict[str, float]]:

@@ -32,6 +32,17 @@ Fault tolerance (see :mod:`repro.serve.reliability`):
   incompatible rows, a cancelled future) can kill the worker loop: every
   future of the affected batch resolves with a structured error and the
   loop keeps serving.
+
+Pipelining: ``predict`` launches a batch and may return its outputs
+unforced (JAX async dispatch); the worker forces them with ``np.asarray``.
+Where a whole ``max_batch`` bucket of rows is already queued behind a
+launched batch, the worker leaves it unforced, gathers and launches the
+next batch, and only then forces and scatters the first: the host's call,
+the kernel and the copy back of one batch run while the next batch's
+input copies.  Otherwise it forces the batch at once.  At most one batch
+is ever unforced.  A pipelined batch whose launch or force raises has
+spent its first dispatch attempt and goes on, blocking, down the retry
+and bisection path.
 """
 
 from __future__ import annotations
@@ -43,7 +54,7 @@ import threading
 import time
 import zlib
 from concurrent.futures import Future
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -162,6 +173,17 @@ class _Request:
     queued: Optional[Tuple[int, float]] = None
 
 
+@dataclasses.dataclass(slots=True)
+class _Launch:
+    """A batch handed to ``predict`` whose outputs are not forced yet."""
+    batch: list
+    rows: int
+    x: np.ndarray  # the (bucket, ...) input
+    t_call: float  # on the batcher's clock: the start of its device_s
+    out: Any = None
+    meta: Optional[dict] = None
+
+
 def _taken(req: _Request, taker: int) -> None:
     """Record ``req``'s wait in the queue, ending now, under the span id
     of the batch or fleet round that took it."""
@@ -243,12 +265,15 @@ class MicroBatcher:
         self.n_retries = 0        # dispatch retries after transient faults
         self.n_dispatch_failures = 0  # failed dispatch attempts
         self.n_failed_requests = 0    # requests resolved with an error
+        # The launched batch left unforced behind a queued full bucket
+        # (worker only; see _step).
+        self._inflight: Optional[_Launch] = None
         # Zero-copy assembly state.  Per-(bucket, row shape, dtype) pair of
-        # preallocated staging buffers, used alternately: JAX dispatch is
-        # async, so the host->device copy of round t may still be reading
-        # buffer A while round t+1 assembles into buffer B.  Allocation
-        # happens once per key — the steady state writes rows into a
-        # long-lived buffer instead of concatenate + fresh pad per dispatch.
+        # preallocated staging buffers, used alternately: the batch in
+        # flight may still be reading buffer A while the next batch
+        # assembles into buffer B.  Allocation happens once per key — the
+        # steady state writes rows into a long-lived buffer instead of
+        # concatenate + fresh pad per dispatch.
         self._staging: dict = {}
         self._staging_parity: dict = {}
         self.n_staging_allocs = 0       # staging buffers ever allocated
@@ -256,7 +281,7 @@ class MicroBatcher:
         self.n_concat_assemblies = 0    # legacy concatenate fallbacks
         self.n_batch1_fastpath = 0      # lone full-bucket requests, no copy
         self.assembly_s = 0.0           # host batch-assembly time
-        self.device_s = 0.0             # predict + result materialization
+        self.device_s = 0.0  # each batch's predict call to its forced outputs
         # Optional hook fired after every successful submit() enqueue — the
         # fleet coalescer's wake-up signal (no-arg callable, must not raise).
         self.on_enqueue: Optional[Callable[[], None]] = None
@@ -382,13 +407,16 @@ class MicroBatcher:
         """Block for the first live request of the next batch.  Requests
         already past their deadline are resolved with
         :class:`DeadlineExceeded` and never join a batch.  Returns None on
-        shutdown sentinel or detach."""
+        shutdown sentinel or detach.  The batch in flight is landed before
+        the worker blocks on an empty queue."""
         first = self._carry
         self._carry = None
         while True:
             if first is None:
                 if self._detached:
                     return None
+                if self._inflight is not None and self._queue.empty():
+                    self._land()
                 first = self._queue.get()
                 if first is None:
                     return None
@@ -497,28 +525,31 @@ class MicroBatcher:
     def serve(self, batch: list) -> None:
         """Serve an externally-collected micro-batch on the caller's thread
         (lazy bucket warmup included) — the coalescer's per-member solo and
-        fallback path.  Single-caller, like the worker loop it replaces."""
+        fallback path.  Single-caller, like the worker loop it replaces;
+        blocking: the batch is forced before this returns."""
         if not batch:
             return
         with spans.span("repro.batch") as sp:
-            self._serve_collected(batch, sp)
+            self._open(batch, sp)
+            self._serve(batch)
 
-    def _serve_collected(self, batch: list, sp) -> None:
-        """Serve a collected batch under its ``repro.batch`` span ``sp``."""
+    def _open(self, batch: list, sp) -> None:
+        """Describe a collected batch on its ``repro.batch`` span ``sp``
+        and warm the buckets before its first dispatch."""
         if sp.id:
             rows = sum(r.x.shape[0] for r in batch)
             sp.set(requests=len(batch), rows=rows,
                    bucket=self.policy.bucket_for(rows))
         if self.policy.warmup and not self._warmed:
             self._warmup(batch[0].x)
-        self._serve(batch)
 
     def _warmup(self, example: np.ndarray) -> None:
         """Trace every bucket once (zero rows shaped like the example)."""
         for b in self.policy.buckets():
             zeros = np.zeros((b,) + example.shape[1:], example.dtype)
             try:
-                self.predict(zeros)
+                out = self.predict(zeros)
+                np.asarray(out[0] if type(out) is tuple else out)
             except Exception:
                 pass  # real traffic will surface the error with context
         self._warmed = True
@@ -528,11 +559,12 @@ class MicroBatcher:
         """The next staging buffer for this (bucket, row shape, dtype).
 
         Two buffers per key, returned alternately: with JAX async dispatch
-        the device copy of the previous round may still be in flight, so
-        the round being assembled must never write the buffer the in-flight
-        round was handed.  A pipeline depth of 1 (enforced by the
-        result-forcing ``np.asarray`` in :meth:`_dispatch_once` and by the
-        coalescer's finalize-before-next-round ordering) makes two enough.
+        an unforced batch may still be copying its input to the device, so
+        the batch being assembled must never write the buffer the unforced
+        one was handed.  At most one batch is unforced while another is
+        assembled (:meth:`_step` forces the batch in flight before it
+        assembles again, and the coalescer finalizes its round in flight
+        before the next round's), so two are enough.
         """
         key = (bucket,) + tuple(trailing) + (np.dtype(dtype).str,)
         bufs = self._staging.get(key)
@@ -592,98 +624,134 @@ class MicroBatcher:
                 "assembly_s": self.assembly_s,
                 "device_s": self.device_s}
 
-    def _dispatch_once(self, batch: list) -> None:
-        """One dispatch attempt for ``batch``: assemble into the bucket, run
-        predict, record stats, scatter results.  Raises on predict failure
-        (nothing resolved); on success every future in ``batch`` resolves."""
+    def _assembled(self, batch: list) -> _Launch:
+        """Assemble ``batch`` into its bucket, ready to launch."""
         rows = sum(r.x.shape[0] for r in batch)
-        bucket = self.policy.bucket_for(rows)
         t0 = self._clock()
         with spans.span("repro.batch.assemble"):
-            x = self._assemble(batch, rows, bucket)
+            x = self._assemble(batch, rows, self.policy.bucket_for(rows))
         t1 = self._clock()
-        with spans.span("repro.batch.dispatch"):
-            out = self.predict(x)
-            meta = None
-            if type(out) is tuple:  # (outputs, batch metadata)
-                out, meta = out
-            # np.asarray forces the async device computation — everything
-            # after t1 up to here is dispatch + device time, split from
-            # assembly time.
-            y = np.asarray(out)[:rows]
         self.assembly_s += t1 - t0
-        self.device_s += self._clock() - t1
-        with spans.span("repro.batch.scatter"):
-            if self._on_dispatch is not None:
-                try:
-                    self._on_dispatch(True, None)
-                except Exception:
-                    pass
-            done = self._clock()
-            # Stats are recorded BEFORE the futures resolve: a caller woken
-            # by its result (e.g. an HTTP client that immediately queries
-            # /v1/stats) must already see the batch that served it counted.
-            if self._on_batch is not None:
-                try:
-                    self._on_batch(len(batch), rows, bucket,
-                                   [done - r.t_enqueue for r in batch],
-                                   meta=meta)
-                except Exception:
-                    pass  # a stats sink must never take down serving
-            off = 0
-            for r in batch:
-                n = r.x.shape[0]
-                if meta is not None:
-                    # Stamped before set_result: a waiter woken by the
-                    # result can always read the meta of the batch that
-                    # served it.
-                    r.future.batch_meta = meta
-                try:
-                    r.future.set_result(y[off:off + n])
-                except BaseException:
-                    pass  # cancelled/raced future; keep scattering the rest
-                off += n
+        return _Launch(batch, rows, x, t1)
 
-    def _try_dispatch(self, batch: list) -> Optional[BaseException]:
+    def _call(self, fl: _Launch) -> None:
+        """Launch an assembled batch: call predict, leave its outputs
+        unforced."""
+        out = self.predict(fl.x)
+        if type(out) is tuple:  # (outputs, batch metadata)
+            out, fl.meta = out
+        fl.out = out
+
+    def _force(self, fl: _Launch) -> np.ndarray:
+        """Wait for a launched batch's outputs: the one place the worker
+        syncs with the device.  Its device_s runs from its call to here."""
+        with spans.span("repro.predict.sync", rows=len(fl.x)):
+            y = np.asarray(fl.out)[:fl.rows]
+        self.device_s += self._clock() - fl.t_call
+        return y
+
+    def _scatter(self, fl: _Launch, y: np.ndarray) -> None:
+        """Record a forced batch's stats, then resolve its futures."""
+        if self._on_dispatch is not None:
+            try:
+                self._on_dispatch(True, None)
+            except Exception:
+                pass
+        done = self._clock()
+        batch, meta = fl.batch, fl.meta
+        # Stats are recorded BEFORE the futures resolve: a caller woken
+        # by its result (e.g. an HTTP client that immediately queries
+        # /v1/stats) must already see the batch that served it counted.
+        if self._on_batch is not None:
+            try:
+                self._on_batch(len(batch), fl.rows,
+                               self.policy.bucket_for(fl.rows),
+                               [done - r.t_enqueue for r in batch],
+                               meta=meta)
+            except Exception:
+                pass  # a stats sink must never take down serving
+        off = 0
+        for r in batch:
+            n = r.x.shape[0]
+            if meta is not None:
+                # Stamped before set_result: a waiter woken by the result
+                # can always read the meta of the batch that served it.
+                r.future.batch_meta = meta
+            try:
+                r.future.set_result(y[off:off + n])
+            except BaseException:
+                pass  # cancelled/raced future; keep scattering the rest
+            off += n
+
+    def _failed_attempt(self, e: BaseException) -> None:
+        self.n_dispatch_failures += 1
+        if self._on_dispatch is not None:
+            try:
+                self._on_dispatch(False, e)
+            except Exception:
+                pass
+
+    def _dispatch_once(self, batch: list) -> None:
+        """One blocking dispatch attempt for ``batch``: assemble into the
+        bucket, launch, force, record stats, scatter results.  Raises on
+        failure (nothing resolved); on success every future in ``batch``
+        resolves."""
+        fl = self._assembled(batch)
+        with spans.span("repro.batch.dispatch", overlapped=0):
+            self._call(fl)
+            y = self._force(fl)
+        with spans.span("repro.batch.scatter"):
+            self._scatter(fl, y)
+
+    def _try_dispatch(self, batch: list, failed: int = 0,
+                      last: Optional[BaseException] = None
+                      ) -> Optional[BaseException]:
         """Dispatch with bounded transient retry; returns None on success
-        (futures resolved) or the final exception (nothing resolved)."""
+        (futures resolved) or the final exception (nothing resolved).
+        ``failed`` attempts were already made, the last raising ``last``
+        (a pipelined batch's first attempt)."""
         attempts = self.retry.max_attempts if self.retry is not None else 1
-        last: Optional[BaseException] = None
-        for attempt in range(attempts):
+        while True:
+            if last is not None:
+                if (self.retry is None or failed >= attempts
+                        or not self.retry.retryable(last)):
+                    return last
+                self.n_retries += 1
+                self._sleep(self.retry.backoff_s(failed - 1, self._rng))
             try:
                 self._dispatch_once(batch)
                 return None
             except Exception as e:
                 last = e
-                self.n_dispatch_failures += 1
-                if self._on_dispatch is not None:
-                    try:
-                        self._on_dispatch(False, e)
-                    except Exception:
-                        pass
-                if (self.retry is None or attempt + 1 >= attempts
-                        or not self.retry.retryable(e)):
-                    return last
-                self.n_retries += 1
-                self._sleep(self.retry.backoff_s(attempt, self._rng))
-        return last
+                failed += 1
+                self._failed_attempt(e)
 
-    def _serve(self, batch: list, isolated: bool = False) -> None:
+    def _live(self, batch: list) -> list:
+        """``batch`` less its requests past their deadline, resolved with
+        :class:`DeadlineExceeded`."""
+        now = self._clock()
+        live = []
+        for r in batch:
+            if self._expired(r, now):
+                self._expire(r)
+            else:
+                live.append(r)
+        return live
+
+    def _serve(self, batch: list, isolated: bool = False,
+               failed: Optional[BaseException] = None) -> None:
         """Serve ``batch``: expire the stale, dispatch the live, bisect on
-        failure so a poison request fails alone.  Every future in ``batch``
-        is resolved by the time this returns; nothing escapes (the worker
-        loop must survive any predict/concatenate/future misbehavior)."""
+        failure so a poison request fails alone.  ``failed``: the batch's
+        first attempt, a pipelined one, already raised it.  Every future in
+        ``batch`` is resolved by the time this returns; nothing escapes
+        (the worker loop must survive any predict/concatenate/future
+        misbehavior)."""
         try:
-            now = self._clock()
-            live = []
-            for r in batch:
-                if self._expired(r, now):
-                    self._expire(r)
-                else:
-                    live.append(r)
+            live = self._live(batch)
             if not live:
                 return
-            err = self._try_dispatch(live)
+            err = (self._try_dispatch(live) if failed is None
+                   else self._try_dispatch(live, 1, failed))
             if err is None:
                 return
             if len(live) == 1:
@@ -708,12 +776,105 @@ class MicroBatcher:
                     _fail(r.future, DispatchError(
                         f"scheduler error on '{self.name}': {e!r}", cause=e))
 
+    def _full_bucket_queued(self, fl: _Launch) -> bool:
+        """Whether the requests queued now, the carried one first, hold a
+        whole ``max_batch`` of rows ahead of any shutdown or detach.
+
+        Only asked when ``fl`` itself was cut by size (a full bucket, or a
+        request carried over): a batch whose gather emptied the queue has
+        only what arrived during its assembly and call behind it, and the
+        scan would cost every such batch its time."""
+        if fl.rows < self.policy.max_batch and self._carry is None:
+            return False
+        need = self.policy.max_batch
+        if self._carry is not None:
+            need -= self._carry.x.shape[0]
+        with self._queue.mutex:
+            for req in self._queue.queue:
+                if need <= 0:
+                    break
+                if req is None or req is _DETACH:
+                    return False
+                need -= req.x.shape[0]
+        return need <= 0
+
+    def _step(self, batch: list) -> None:
+        """The worker's turn for a collected ``batch``, under its
+        ``repro.batch`` span: launch it, force and scatter the batch in
+        flight, if any, and force ``batch`` too unless a full bucket is
+        queued behind it, in which case it becomes the batch in flight."""
+        prev, self._inflight = self._inflight, None
+        landed: List[tuple] = []  # (launch, outputs)
+        failed: List[tuple] = []  # (batch, exception of its first attempt)
+        try:
+            live = self._live(batch)
+            fl = None
+            if live:
+                try:
+                    fl = self._assembled(live)
+                except Exception as e:
+                    failed.append((live, e))
+            with spans.span("repro.batch.dispatch", overlapped=int(
+                    prev is not None and fl is not None)):
+                if fl is not None:
+                    try:
+                        self._call(fl)
+                    except Exception as e:
+                        failed.append((live, e))
+                        fl = None
+                for f in (prev, fl):
+                    if f is None:
+                        continue
+                    if (f is fl and not failed
+                            and self._full_bucket_queued(fl)):
+                        self._inflight = fl
+                        continue
+                    try:
+                        landed.append((f, self._force(f)))
+                    except Exception as e:
+                        failed.append((f.batch, e))
+            self._resolve(landed, failed)
+        except Exception as e:  # belt-and-braces: resolve, don't die
+            self._inflight = None
+            for r in batch + (prev.batch if prev is not None else []):
+                if not r.future.done():
+                    self.n_failed_requests += 1
+                    _fail(r.future, DispatchError(
+                        f"scheduler error on '{self.name}': {e!r}", cause=e))
+
+    def _resolve(self, landed: list, failed: list) -> None:
+        """Scatter forced batches; send each batch whose first attempt
+        failed down the blocking retry and bisection path."""
+        with spans.span("repro.batch.scatter"):
+            for fl, y in landed:
+                self._scatter(fl, y)
+        for batch, e in failed:
+            self._failed_attempt(e)
+            self._serve(batch, failed=e)
+
+    def _land(self) -> None:
+        """Force and scatter the batch in flight, if any: nothing live is
+        queued behind it, or the worker is leaving."""
+        fl, self._inflight = self._inflight, None
+        if fl is None:
+            return
+        landed, failed = [], []
+        with spans.span("repro.batch"):
+            with spans.span("repro.batch.dispatch", overlapped=0):
+                try:
+                    landed.append((fl, self._force(fl)))
+                except Exception as e:
+                    failed.append((fl.batch, e))
+            self._resolve(landed, failed)
+
     def _run(self) -> None:
         while True:
             first = self._first()  # the blocking wait: idle, not a span
             if first is None:
+                self._land()
                 return
             with spans.span("repro.batch") as sp:
                 with spans.span("repro.batch.collect"):
                     batch = self._gather(first, sp.id)
-                self._serve_collected(batch, sp)
+                self._open(batch, sp)
+                self._step(batch)

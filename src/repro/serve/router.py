@@ -214,16 +214,18 @@ class Endpoint:
             self.breaker.record_failure()
 
     def _dispatch(self, x: np.ndarray):
-        """The batcher's predict: resolve which artifact serves this batch.
+        """The batcher's predict: resolve which artifact serves this batch
+        and launch it, leaving the outputs unforced (the batcher forces
+        them).
 
-        Returns ``(rows, meta)`` once a fallback is armed — the batcher
+        Returns ``(outputs, meta)`` once a fallback is armed — the batcher
         forwards ``meta`` to the stats sink and stamps it on every future of
         the batch, so callers (the HTTP front end) can report whether their
         prediction came from the degraded artifact.
         """
         faults.fire("endpoint.dispatch", name=self.name, batch=x)
         if self.governor is None:
-            return self.artifact.predict(x)
+            return self.artifact.predict_device(x)
         # A tripped breaker is an overload vote: serve probes (and the
         # post-trip backlog) on the cheap artifact until health returns.
         hint = (self.breaker is not None
@@ -232,7 +234,7 @@ class Endpoint:
             self.batcher.depth() if self.batcher is not None else 0,
             self.stats.rolling_p99_ms(), overload_hint=hint)
         art = self.fallback if degraded else self.artifact
-        return art.predict(x), {"degraded": degraded,
+        return art.predict_device(x), {"degraded": degraded,
                                 "number_format": art.target.number_format}
 
     def fleet_route(self) -> bool:

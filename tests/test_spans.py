@@ -353,3 +353,75 @@ def test_spans_keep_the_real_clock_under_an_injected_one(tmp_path):
     recs = spans.collected(float("-inf"), float("inf"))
     assert {r[0] for r in recs} >= {"repro.batch", "repro.request.queue"}
     assert all(t0 <= r[1] <= r[2] <= t1 for r in recs)
+
+
+@pytest.fixture(scope="module")
+def pipelined(service, rows, tmp_path_factory):
+    """A traced bulk request of eight 64-row blocks on an xla endpoint,
+    queued behind a first dispatch held by a delay fault, then three
+    single-row requests: the records of the endpoint's worker thread."""
+    from repro.serve import ModelRouter, faults
+    from repro.serve.faults import FaultPlan, FaultRule
+
+    art = service.endpoint(SOLO).artifact
+    for b in (1, 64):
+        art.predict(np.zeros((b, F), np.float32))
+    router = ModelRouter()
+    ep = router.register("bulk", art, policy=BatchingPolicy(
+        max_batch=64, warmup=False))
+    bulk = np.tile(rows, (8, 1))
+    plan = FaultPlan([FaultRule(site="endpoint.dispatch", kind="delay",
+                                delay_s=0.2, count=1)])
+    try:
+        spans.clear()
+        with jax.profiler.trace(str(tmp_path_factory.mktemp("bulk"))):
+            with faults.inject(plan):
+                np.testing.assert_array_equal(ep.predict(bulk),
+                                              art.predict(bulk))
+            for k in range(3):
+                ep.submit(rows[k]).result(timeout=60)
+        worker = ep.batcher._worker.ident
+    finally:
+        router.close()
+    return [r for r in spans.collected(float("-inf"), float("inf"))
+            if r[3] == worker]
+
+
+def test_batch_dispatch_spans_say_whether_they_overlapped(pipelined):
+    by_id = _by_id(pipelined)
+    flags = collections.defaultdict(list)
+    for r in pipelined:
+        if r[0] == "repro.batch.dispatch":
+            flags[_ints(by_id[r[5]])["rows"]].append(_ints(r)["overlapped"])
+    # The first block of the request launches with nothing in flight; each
+    # of the other seven launches behind the block before, still unforced.
+    assert flags[64] == [0] + [1] * 7
+    assert flags[1] == [0, 0, 0]
+    assert set(flags) == {1, 64}
+
+
+def test_pipelined_program_spans_nest_under_dispatch_under_batch(pipelined):
+    by_id = _by_id(pipelined)
+    batches = {r[4] for r in pipelined
+               if r[0] == "repro.batch" and r[5] == 0}
+    assert len(batches) == 11
+    kids = collections.defaultdict(list)
+    for r in pipelined:
+        if r[5] in batches:
+            kids[r[5]].append(r[0])
+    for bid in batches:
+        assert sorted(kids[bid]) == ["repro.batch.assemble",
+                                     "repro.batch.collect",
+                                     "repro.batch.dispatch",
+                                     "repro.batch.scatter"]
+    chain = collections.Counter(
+        (r[0], by_id[r[5]][0], by_id[by_id[r[5]][5]][0]) for r in pipelined
+        if r[0].startswith("repro.predict."))
+    assert chain == {("repro.predict.call", "repro.batch.dispatch",
+                      "repro.batch"): 11,
+                     ("repro.predict.sync", "repro.batch.dispatch",
+                      "repro.batch"): 11}
+    for r in pipelined:
+        if r[5]:
+            parent = by_id[r[5]]
+            assert parent[1] <= r[1] and r[2] <= parent[2]

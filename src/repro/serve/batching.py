@@ -43,6 +43,13 @@ input copies.  Otherwise it forces the batch at once.  At most one batch
 is ever unforced.  A pipelined batch whose launch or force raises has
 spent its first dispatch attempt and goes on, blocking, down the retry
 and bisection path.
+
+One scheduling core serves this worker and the fleet coalescer
+(:mod:`repro.serve.fleet`): :meth:`MicroBatcher.gather` is the one gather
+loop (the worker waits in it behind the request it blocked for, the
+coalescer does not wait), and :class:`SchedulerCore` holds the staging
+store, the :class:`Launch` record, the force and the scatter.  Each
+scheduler keeps its own rule for leaving a launch unforced.
 """
 
 from __future__ import annotations
@@ -78,9 +85,6 @@ class BatchingPolicy:
       sequential client is not taxed the wait on every request.  Disable to
       always hold for ``max_wait_ms`` (maximum fill under slow open-loop
       arrivals, at a latency cost).
-    * ``bucketing``   — ``pow2``: pad each micro-batch up to the next
-      power-of-two bucket (closed shape set, one jit trace per bucket);
-      ``exact``: no padding (every distinct batch size traces afresh).
     * ``warmup``      — trace every bucket with zero rows before the first
       micro-batch is served (triggered lazily by the first request, which
       supplies the row shape and therefore absorbs the trace latency;
@@ -96,7 +100,6 @@ class BatchingPolicy:
     max_batch: int = 64
     max_wait_ms: float = 2.0
     eager_when_idle: bool = True
-    bucketing: str = "pow2"
     warmup: bool = True
     replicas: int = 1
 
@@ -105,16 +108,12 @@ class BatchingPolicy:
             raise ValueError("max_batch must be >= 1")
         if self.max_wait_ms < 0:
             raise ValueError("max_wait_ms must be >= 0")
-        if self.bucketing not in ("pow2", "exact"):
-            raise ValueError("bucketing must be 'pow2' or 'exact'")
         if self.replicas < 1:
             raise ValueError("replicas must be >= 1")
 
     def buckets(self) -> Tuple[int, ...]:
-        """The closed set of batch shapes predict will be called with (in
-        exact mode there is no closed set; only the cap is warmed up)."""
-        if self.bucketing == "exact":
-            return (self.max_batch,)
+        """The closed set of batch shapes predict will be called with:
+        powers of two (times ``replicas``) up to ``max_batch``."""
         out, b = [], min(self.replicas, self.max_batch)
         while b < self.max_batch:
             out.append(b)
@@ -123,9 +122,7 @@ class BatchingPolicy:
         return tuple(out)
 
     def bucket_for(self, n: int) -> int:
-        """Smallest bucket holding ``n`` rows (``n`` itself in exact mode)."""
-        if self.bucketing == "exact":
-            return n
+        """Smallest bucket holding ``n`` rows."""
         for b in self.buckets():
             if b >= n:
                 return b
@@ -174,14 +171,17 @@ class _Request:
 
 
 @dataclasses.dataclass(slots=True)
-class _Launch:
-    """A batch handed to ``predict`` whose outputs are not forced yet."""
-    batch: list
-    rows: int
-    x: np.ndarray  # the (bucket, ...) input
-    t_call: float  # on the batcher's clock: the start of its device_s
+class Launch:
+    """A batch, or a fleet round, handed to its program whose outputs are
+    not forced yet."""
+    batch: list  # requests; a round's (slot, endpoint, requests, rows)
+    bucket: int
+    x: np.ndarray  # the padded input: (bucket, ...); a round's (E, bucket, F)
+    t_call: float  # on the owner's clock: the start of its device_s
+    rows: int = 0  # real rows of a batch (a round's riders carry their own)
     out: Any = None
     meta: Optional[dict] = None
+    taker: int = 0  # span id of the fleet round that launched it
 
 
 def _taken(req: _Request, taker: int) -> None:
@@ -202,8 +202,8 @@ def _fail(fut: Future, exc: BaseException) -> None:
         pass
 
 
-# detach_worker() wake-up sentinel: tells the worker thread to exit while
-# leaving the batcher open for an external driver (the fleet coalescer).
+# detach_worker() wake-up sentinel: wakes the worker's blocking wait so it
+# exits, leaving the batcher open for an external scheduler (the coalescer).
 _DETACH = object()
 
 
@@ -215,7 +215,75 @@ OnBatch = Callable[[int, int, int, Sequence[float]], None]
 OnDispatch = Callable[[bool, Optional[BaseException]], None]
 
 
-class MicroBatcher:
+class SchedulerCore:
+    """What the micro-batcher and the fleet coalescer share below their
+    gather: one clock, the staging store, the force and the scatter.
+
+    Either scheduler leaves at most one launch unforced while it assembles
+    the next (the micro-batcher while a full bucket is queued behind it,
+    the coalescer until the next round's launch or inside its hold), so
+    two staging buffers per shape are enough: with JAX async dispatch the
+    unforced launch may still be copying its input to the device, and the
+    batch being assembled never writes the buffer it was handed.
+    """
+
+    def __init__(self, clock: Optional[Callable[[], float]] = None):
+        self._clock = clock or time.perf_counter
+        self._staging: dict = {}  # (shape, dtype) -> [buffer, buffer]
+        self.n_staging_allocs = 0  # staging buffers ever allocated
+        self.assembly_s = 0.0  # host batch-assembly time
+        self.device_s = 0.0  # each launch's call to its forced outputs
+
+    def _staging_buffer(self, shape: tuple, dtype) -> np.ndarray:
+        """The next of the two staging buffers of ``shape`` and ``dtype``,
+        used alternately and allocated once: the steady state writes rows
+        into long-lived buffers, not a fresh pad per dispatch."""
+        pair = self._staging.get((shape, dtype))
+        if pair is None:
+            pair = [np.zeros(shape, dtype), np.zeros(shape, dtype)]
+            self._staging[shape, dtype] = pair
+            self.n_staging_allocs += 2
+        pair.reverse()
+        return pair[0]
+
+    def _force(self, fl: Launch, rows: int) -> np.ndarray:
+        """Wait for a launch's outputs: the one place either scheduler
+        syncs with the device.  ``rows`` (padding included) names the sync
+        span's input; device_s runs from the launch's call to here."""
+        with spans.span("repro.predict.sync", rows=rows):
+            y = np.asarray(fl.out)
+        self.device_s += self._clock() - fl.t_call
+        return y
+
+    @staticmethod
+    def _scatter(batch: list, y, rows: int, bucket: int,
+                 meta: Optional[dict], done: float,
+                 on_batch: Optional[OnBatch]) -> None:
+        """Resolve the requests of a forced batch from its outputs ``y``.
+
+        Stats are recorded BEFORE the futures resolve: a caller woken by
+        its result (e.g. an HTTP client that immediately queries
+        /v1/stats) must already see the batch that served it counted; the
+        meta is stamped before the result for the same reason."""
+        if on_batch is not None:
+            try:
+                on_batch(len(batch), rows, bucket,
+                         [done - r.t_enqueue for r in batch], meta=meta)
+            except Exception:
+                pass  # a stats sink must never take down serving
+        off = 0
+        for r in batch:
+            n = r.x.shape[0]
+            if meta is not None:
+                r.future.batch_meta = meta
+            try:
+                r.future.set_result(y[off:off + n])
+            except BaseException:
+                pass  # cancelled/raced future; keep scattering the rest
+            off += n
+
+
+class MicroBatcher(SchedulerCore):
     """Single-worker dynamic micro-batching loop over one predict callable.
 
     ``predict(x: (bucket, ...)) -> (bucket, ...) per-row outputs``; any
@@ -243,13 +311,13 @@ class MicroBatcher:
                  on_dispatch: Optional[OnDispatch] = None,
                  clock: Optional[Callable[[], float]] = None,
                  sleep: Optional[Callable[[float], None]] = None):
+        super().__init__(clock)
         self.predict = predict
         self.policy = policy or BatchingPolicy()
         self.name = name
         self.retry = retry
         self._on_batch = on_batch
         self._on_dispatch = on_dispatch
-        self._clock = clock or time.perf_counter
         self._sleep = sleep or time.sleep
         # Deterministic per-endpoint jitter stream (stable across restarts).
         self._rng = random.Random(zlib.crc32(name.encode()) & 0xFFFFFFFF)
@@ -267,21 +335,10 @@ class MicroBatcher:
         self.n_failed_requests = 0    # requests resolved with an error
         # The launched batch left unforced behind a queued full bucket
         # (worker only; see _step).
-        self._inflight: Optional[_Launch] = None
-        # Zero-copy assembly state.  Per-(bucket, row shape, dtype) pair of
-        # preallocated staging buffers, used alternately: the batch in
-        # flight may still be reading buffer A while the next batch
-        # assembles into buffer B.  Allocation happens once per key — the
-        # steady state writes rows into a long-lived buffer instead of
-        # concatenate + fresh pad per dispatch.
-        self._staging: dict = {}
-        self._staging_parity: dict = {}
-        self.n_staging_allocs = 0       # staging buffers ever allocated
+        self._inflight: Optional[Launch] = None
         self.n_zero_copy_assemblies = 0  # batches assembled into staging
         self.n_concat_assemblies = 0    # legacy concatenate fallbacks
         self.n_batch1_fastpath = 0      # lone full-bucket requests, no copy
-        self.assembly_s = 0.0           # host batch-assembly time
-        self.device_s = 0.0  # each batch's predict call to its forced outputs
         # Optional hook fired after every successful submit() enqueue — the
         # fleet coalescer's wake-up signal (no-arg callable, must not raise).
         self.on_enqueue: Optional[Callable[[], None]] = None
@@ -430,29 +487,51 @@ class MicroBatcher:
             self._expire(first)
             first = None
 
-    def _gather(self, first: _Request, taker: int) -> list:
-        """Gather a batch behind ``first`` until it is full or the first
-        request's ``max_wait_ms`` budget runs out (expired requests are
-        resolved and skipped); ``taker`` is the batch's span id."""
-        _taken(first, taker)
-        batch, rows = [first], first.x.shape[0]
-        deadline = first.t_enqueue + self.policy.max_wait_ms / 1e3
+    def gather(self, taker: int = 0,
+               first: Optional[_Request] = None) -> list:
+        """Gather the next micro-batch, possibly [].  ``taker`` is the span
+        id of the batch or fleet round that takes it, which each request's
+        ``repro.request.queue`` interval names.
+
+        The worker passes ``first``, the live request it blocked for, and
+        waits up to that request's ``max_wait_ms`` for company unless
+        ``eager_when_idle``.  An external scheduler (the fleet coalescer, once
+        the worker is detached) passes none: the batch starts at the carried
+        request and nothing waits.  Either way a request past its deadline
+        is resolved with :class:`DeadlineExceeded` and skipped, one that
+        would overflow ``max_batch`` is carried to head the next batch, a
+        ``close()`` sentinel ends the batch and stays queued for the final
+        drain, and a detach wake-up is skipped."""
+        deadline = None
+        if first is None:
+            first, self._carry = self._carry, None
+            if first is not None and self._expired(first):
+                self._expire(first)
+                first = None
+        elif not self.policy.eager_when_idle:
+            deadline = first.t_enqueue + self.policy.max_wait_ms / 1e3
+        if first is None:
+            batch, rows = [], 0
+        else:
+            _taken(first, taker)
+            batch, rows = [first], first.x.shape[0]
         while rows < self.policy.max_batch:
-            wait = deadline - self._clock()
             try:
-                if wait <= 0 or self.policy.eager_when_idle:
+                if deadline is None:
                     req = self._queue.get_nowait()
                 else:
-                    req = self._queue.get(timeout=wait)
+                    wait = deadline - self._clock()
+                    req = (self._queue.get(timeout=wait) if wait > 0
+                           else self._queue.get_nowait())
             except queue.Empty:
-                if wait <= 0 or self.policy.eager_when_idle:
+                if deadline is None or wait <= 0:
                     break
                 continue
-            if req is None:  # shutdown: serve what we have, then exit
+            if req is None:  # shutdown: keep the sentinel for the drain
                 self._queue.put(None)
                 break
-            if req is _DETACH:  # detach: serve what we have, then exit
-                break
+            if req is _DETACH:
+                continue  # a wake-up only; the worker leaves after this batch
             if self._expired(req):
                 self._expire(req)
                 continue
@@ -469,7 +548,7 @@ class MicroBatcher:
         """Retire the internal worker thread WITHOUT closing the batcher.
 
         Afterward ``submit`` keeps enqueueing but nothing serves the queue
-        until an external driver does, via :meth:`collect_nowait` +
+        until an external scheduler does, via :meth:`gather` and
         :meth:`serve` — how the fleet coalescer takes over a member
         endpoint's scheduling while preserving its client-facing API.
         Idempotent; :meth:`close` still drains whatever remains.
@@ -477,61 +556,41 @@ class MicroBatcher:
         if self._worker is None:
             return
         self._detached = True
-        self._queue.put(_DETACH)  # wake a blocked _collect
+        self._queue.put(_DETACH)  # wake a blocked _first
         self._worker.join(timeout)
         if self._worker.is_alive():  # pragma: no cover - defensive
             raise RuntimeError(
                 f"MicroBatcher '{self.name}' worker did not detach")
         self._worker = None
 
-    def collect_nowait(self, taker: int = 0) -> list:
-        """Gather the next micro-batch without blocking (external drivers
-        only — the internal worker must be detached).  Returns possibly-[].
-        Honors carry/deadlines/max_batch exactly like the worker's collect;
-        preserves a close() sentinel for the final drain.  ``taker`` is the
-        span id of the caller's round, which the requests' queue waits
-        name."""
-        batch: list = []
-        rows = 0
-        first = self._carry
-        self._carry = None
-        if first is not None:
-            if self._expired(first):
-                self._expire(first)
-            else:
-                _taken(first, taker)
-                batch, rows = [first], first.x.shape[0]
-        while rows < self.policy.max_batch:
-            try:
-                req = self._queue.get_nowait()
-            except queue.Empty:
-                break
-            if req is None:
-                self._queue.put(None)  # keep the shutdown sentinel
-                break
-            if req is _DETACH:
-                continue  # stale wake-up; the driver is already here
-            if self._expired(req):
-                self._expire(req)
-                continue
-            if rows + req.x.shape[0] > self.policy.max_batch:
-                self._carry = req
-                break
-            _taken(req, taker)
-            batch.append(req)
-            rows += req.x.shape[0]
-        return batch
-
     def serve(self, batch: list) -> None:
-        """Serve an externally-collected micro-batch on the caller's thread
+        """Serve an externally-gathered micro-batch on the caller's thread
         (lazy bucket warmup included) — the coalescer's per-member solo and
         fallback path.  Single-caller, like the worker loop it replaces;
-        blocking: the batch is forced before this returns."""
+        blocking: every future of the batch is resolved before this
+        returns."""
         if not batch:
             return
         with spans.span("repro.batch") as sp:
             self._open(batch, sp)
             self._serve(batch)
+
+    def warm(self, example: np.ndarray) -> int:
+        """Trace every bucket once with zero rows shaped like ``example``,
+        unless done already or the policy's ``warmup`` is off.  Returns how
+        many traces raised (real traffic surfaces the error with
+        context)."""
+        failed = 0
+        if not self._warmed and self.policy.warmup:
+            for b in self.policy.buckets():
+                try:
+                    out = self.predict(
+                        np.zeros((b,) + example.shape[1:], example.dtype))
+                    np.asarray(out[0] if type(out) is tuple else out)
+                except Exception:
+                    failed += 1
+        self._warmed = True
+        return failed
 
     def _open(self, batch: list, sp) -> None:
         """Describe a collected batch on its ``repro.batch`` span ``sp``
@@ -540,43 +599,8 @@ class MicroBatcher:
             rows = sum(r.x.shape[0] for r in batch)
             sp.set(requests=len(batch), rows=rows,
                    bucket=self.policy.bucket_for(rows))
-        if self.policy.warmup and not self._warmed:
-            self._warmup(batch[0].x)
-
-    def _warmup(self, example: np.ndarray) -> None:
-        """Trace every bucket once (zero rows shaped like the example)."""
-        for b in self.policy.buckets():
-            zeros = np.zeros((b,) + example.shape[1:], example.dtype)
-            try:
-                out = self.predict(zeros)
-                np.asarray(out[0] if type(out) is tuple else out)
-            except Exception:
-                pass  # real traffic will surface the error with context
-        self._warmed = True
-
-    def _staging_buffer(self, bucket: int, trailing: tuple,
-                        dtype) -> np.ndarray:
-        """The next staging buffer for this (bucket, row shape, dtype).
-
-        Two buffers per key, returned alternately: with JAX async dispatch
-        an unforced batch may still be copying its input to the device, so
-        the batch being assembled must never write the buffer the unforced
-        one was handed.  At most one batch is unforced while another is
-        assembled (:meth:`_step` forces the batch in flight before it
-        assembles again, and the coalescer finalizes its round in flight
-        before the next round's), so two are enough.
-        """
-        key = (bucket,) + tuple(trailing) + (np.dtype(dtype).str,)
-        bufs = self._staging.get(key)
-        if bufs is None:
-            bufs = (np.zeros((bucket,) + tuple(trailing), dtype),
-                    np.zeros((bucket,) + tuple(trailing), dtype))
-            self._staging[key] = bufs
-            self._staging_parity[key] = 0
-            self.n_staging_allocs += 2
-        p = self._staging_parity[key]
-        self._staging_parity[key] = p ^ 1
-        return bufs[p]
+        if not self._warmed:
+            self.warm(batch[0].x)
 
     def _assemble(self, batch: list, rows: int, bucket: int) -> np.ndarray:
         """Gather ``batch`` into one (bucket, ...) input without per-dispatch
@@ -602,7 +626,7 @@ class MicroBatcher:
                 pad = np.zeros((bucket - rows,) + x.shape[1:], x.dtype)
                 x = np.concatenate([x, pad], axis=0)
             return x
-        buf = self._staging_buffer(bucket, trailing, dtype)
+        buf = self._staging_buffer((bucket,) + trailing, dtype)
         off = 0
         for r in batch:
             n = r.x.shape[0]
@@ -624,64 +648,24 @@ class MicroBatcher:
                 "assembly_s": self.assembly_s,
                 "device_s": self.device_s}
 
-    def _assembled(self, batch: list) -> _Launch:
+    def _assembled(self, batch: list) -> Launch:
         """Assemble ``batch`` into its bucket, ready to launch."""
         rows = sum(r.x.shape[0] for r in batch)
         t0 = self._clock()
         with spans.span("repro.batch.assemble"):
-            x = self._assemble(batch, rows, self.policy.bucket_for(rows))
+            bucket = self.policy.bucket_for(rows)
+            x = self._assemble(batch, rows, bucket)
         t1 = self._clock()
         self.assembly_s += t1 - t0
-        return _Launch(batch, rows, x, t1)
+        return Launch(batch, bucket, x, t1, rows)
 
-    def _call(self, fl: _Launch) -> None:
+    def _call(self, fl: Launch) -> None:
         """Launch an assembled batch: call predict, leave its outputs
         unforced."""
         out = self.predict(fl.x)
         if type(out) is tuple:  # (outputs, batch metadata)
             out, fl.meta = out
         fl.out = out
-
-    def _force(self, fl: _Launch) -> np.ndarray:
-        """Wait for a launched batch's outputs: the one place the worker
-        syncs with the device.  Its device_s runs from its call to here."""
-        with spans.span("repro.predict.sync", rows=len(fl.x)):
-            y = np.asarray(fl.out)[:fl.rows]
-        self.device_s += self._clock() - fl.t_call
-        return y
-
-    def _scatter(self, fl: _Launch, y: np.ndarray) -> None:
-        """Record a forced batch's stats, then resolve its futures."""
-        if self._on_dispatch is not None:
-            try:
-                self._on_dispatch(True, None)
-            except Exception:
-                pass
-        done = self._clock()
-        batch, meta = fl.batch, fl.meta
-        # Stats are recorded BEFORE the futures resolve: a caller woken
-        # by its result (e.g. an HTTP client that immediately queries
-        # /v1/stats) must already see the batch that served it counted.
-        if self._on_batch is not None:
-            try:
-                self._on_batch(len(batch), fl.rows,
-                               self.policy.bucket_for(fl.rows),
-                               [done - r.t_enqueue for r in batch],
-                               meta=meta)
-            except Exception:
-                pass  # a stats sink must never take down serving
-        off = 0
-        for r in batch:
-            n = r.x.shape[0]
-            if meta is not None:
-                # Stamped before set_result: a waiter woken by the result
-                # can always read the meta of the batch that served it.
-                r.future.batch_meta = meta
-            try:
-                r.future.set_result(y[off:off + n])
-            except BaseException:
-                pass  # cancelled/raced future; keep scattering the rest
-            off += n
 
     def _failed_attempt(self, e: BaseException) -> None:
         self.n_dispatch_failures += 1
@@ -699,9 +683,8 @@ class MicroBatcher:
         fl = self._assembled(batch)
         with spans.span("repro.batch.dispatch", overlapped=0):
             self._call(fl)
-            y = self._force(fl)
-        with spans.span("repro.batch.scatter"):
-            self._scatter(fl, y)
+            y = self._force(fl, fl.bucket)
+        self._resolve([(fl, y)], ())
 
     def _try_dispatch(self, batch: list, failed: int = 0,
                       last: Optional[BaseException] = None
@@ -776,7 +759,7 @@ class MicroBatcher:
                     _fail(r.future, DispatchError(
                         f"scheduler error on '{self.name}': {e!r}", cause=e))
 
-    def _full_bucket_queued(self, fl: _Launch) -> bool:
+    def _full_bucket_queued(self, fl: Launch) -> bool:
         """Whether the requests queued now, the carried one first, hold a
         whole ``max_batch`` of rows ahead of any shutdown or detach.
 
@@ -830,7 +813,7 @@ class MicroBatcher:
                         self._inflight = fl
                         continue
                     try:
-                        landed.append((f, self._force(f)))
+                        landed.append((f, self._force(f, f.bucket)))
                     except Exception as e:
                         failed.append((f.batch, e))
             self._resolve(landed, failed)
@@ -847,7 +830,13 @@ class MicroBatcher:
         failed down the blocking retry and bisection path."""
         with spans.span("repro.batch.scatter"):
             for fl, y in landed:
-                self._scatter(fl, y)
+                if self._on_dispatch is not None:
+                    try:
+                        self._on_dispatch(True, None)
+                    except Exception:
+                        pass
+                self._scatter(fl.batch, y, fl.rows, fl.bucket, fl.meta,
+                              self._clock(), self._on_batch)
         for batch, e in failed:
             self._failed_attempt(e)
             self._serve(batch, failed=e)
@@ -855,17 +844,9 @@ class MicroBatcher:
     def _land(self) -> None:
         """Force and scatter the batch in flight, if any: nothing live is
         queued behind it, or the worker is leaving."""
-        fl, self._inflight = self._inflight, None
-        if fl is None:
-            return
-        landed, failed = [], []
-        with spans.span("repro.batch"):
-            with spans.span("repro.batch.dispatch", overlapped=0):
-                try:
-                    landed.append((fl, self._force(fl)))
-                except Exception as e:
-                    failed.append((fl.batch, e))
-            self._resolve(landed, failed)
+        if self._inflight is not None:
+            with spans.span("repro.batch"):
+                self._step([])
 
     def _run(self) -> None:
         while True:
@@ -875,6 +856,6 @@ class MicroBatcher:
                 return
             with spans.span("repro.batch") as sp:
                 with spans.span("repro.batch.collect"):
-                    batch = self._gather(first, sp.id)
+                    batch = self.gather(sp.id, first)
                 self._open(batch, sp)
                 self._step(batch)

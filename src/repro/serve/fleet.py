@@ -36,29 +36,33 @@ the materialization, and round t's callers do not wait through round
 t+1's hold.  A round that does not hold (a full stack, a lone rider)
 launches first and finalizes round t after, so the sync overlaps its
 compute.
+
+The coalescer runs on the micro-batcher's scheduling core
+(:class:`repro.serve.batching.SchedulerCore`): each member's queue is
+drained by :meth:`MicroBatcher.gather`, and the staging store, the
+:class:`~repro.serve.batching.Launch` record of the round in flight, the
+force and the per-rider scatter are the ones the micro-batcher uses.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, List, Optional
+from typing import List, Optional
 
 import numpy as np
 
 from repro import spans
 
-from .batching import _fail
-from .reliability import DispatchError
+from .batching import Launch, SchedulerCore
 
 __all__ = ["FleetCoalescer"]
 
-# (device_out, stacked=[(slot, endpoint, batch, rows)...], bucket, t_launch,
-#  span id of the launching round)
-_Pending = tuple
+# How long an idle coalescer sleeps between sweeps unless a submit wakes it.
+_IDLE_WAIT_S = 0.05
 
 
-class FleetCoalescer:
+class FleetCoalescer(SchedulerCore):
     """Single-threaded cross-endpoint scheduler over one FleetStack.
 
     ``endpoints`` are the member :class:`~repro.serve.router.Endpoint`\\ s
@@ -67,35 +71,23 @@ class FleetCoalescer:
     members' ``submit`` APIs keep working unchanged, served by this thread.
     """
 
-    def __init__(self, stack, endpoints,
-                 clock: Optional[Callable[[], float]] = None,
-                 idle_wait_s: float = 0.05,
-                 hold_ms: Optional[float] = None):
+    def __init__(self, stack, endpoints):
         if len(endpoints) != stack.n_models:
             raise ValueError(f"{len(endpoints)} endpoints for a "
                              f"{stack.n_models}-model stack")
+        super().__init__()
         self.stack = stack
         self.members = list(endpoints)
-        self._clock = clock or time.perf_counter
-        self._idle_wait_s = idle_wait_s
         # Fill hold: when a round collects some but not all members, wait
         # this long for stragglers before dispatching — a narrow stack
         # wastes the dispatch the whole design exists to amortize.  The
         # members' own max_wait is the latency budget their callers
-        # already accepted, so defaulting to its minimum adds no new tail.
-        self._hold_s = (min(ep.batcher.policy.max_wait_ms
-                            for ep in endpoints) / 1e3
-                        if hold_ms is None else hold_ms / 1e3)
+        # already accepted, so its minimum adds no new tail.
+        self._hold_s = min(ep.policy.max_wait_ms for ep in endpoints) / 1e3
         self._event = threading.Event()
         self._closed = False
         self._warmed = False
-        self._pending: Optional[_Pending] = None
-        # Double-buffered (E, bucket, F) staging, one pair per bucket: the
-        # host->device copy of the in-flight round must never see the
-        # buffer the next round is being assembled into.
-        self._staging: dict = {}
-        self._parity: dict = {}
-        self.n_staging_allocs = 0
+        self._inflight: Optional[Launch] = None  # launched, not finalized
         # Round accounting (single-writer: the coalescer thread).
         self.n_rounds = 0              # stacked rounds launched
         self.n_stacked_dispatches = 0  # == n_rounds unless a launch raised
@@ -104,8 +96,6 @@ class FleetCoalescer:
         self.n_stack_fallbacks = 0     # stacked rounds re-served per member
         self.n_warmup_failures = 0     # warmup traces that raised
         self.n_hold_finalizes = 0      # rounds finalized in the next hold
-        self.assembly_s = 0.0          # host staging-buffer assembly time
-        self.device_s = 0.0            # launch -> materialized outputs
         for ep in self.members:
             ep.batcher.detach_worker()
             ep.batcher.on_enqueue = self._event.set
@@ -148,19 +138,6 @@ class FleetCoalescer:
                 "device_s": self.device_s}
 
     # -- round machinery -----------------------------------------------------
-    def _staging_buffer(self, bucket: int) -> np.ndarray:
-        key = int(bucket)
-        bufs = self._staging.get(key)
-        if bufs is None:
-            shape = (self.stack.n_models, bucket, self.stack.n_features)
-            bufs = (np.zeros(shape, np.float32), np.zeros(shape, np.float32))
-            self._staging[key] = bufs
-            self._parity[key] = 0
-            self.n_staging_allocs += 2
-        p = self._parity[key]
-        self._parity[key] = p ^ 1
-        return bufs[p]
-
     def _warmup(self) -> None:
         """Trace the stacked program over the shared bucket ladder before
         the first live round — and every member's own solo ladder too: a
@@ -177,24 +154,14 @@ class FleetCoalescer:
                 self.n_warmup_failures += 1
         example = np.zeros((1, shape[1]), np.float32)
         for ep in self.members:
-            if ep.batcher.policy.warmup and not ep.batcher._warmed:
-                try:
-                    ep.batcher._warmup(example)
-                except Exception:
-                    # Solo serving will retry with real rows; counted.
-                    self.n_warmup_failures += 1
+            self.n_warmup_failures += ep.batcher.warm(example)
         self._warmed = True
 
     def _serve_solo(self, ep, batch: list) -> None:
         """One member's batch through its own dispatch path (degradation,
-        breaker feed, retries, bisection — unchanged semantics)."""
-        try:
-            ep.batcher.serve(batch)
-        except BaseException as e:  # pragma: no cover - serve() resolves all
-            for r in batch:
-                if not r.future.done():
-                    _fail(r.future, DispatchError(
-                        f"solo serve error on '{ep.name}': {e!r}", cause=e))
+        breaker feed, retries, bisection — unchanged semantics), which
+        resolves every future of it."""
+        ep.batcher.serve(batch)
         self.n_solo_batches += 1
 
     def _round(self) -> bool:
@@ -211,7 +178,7 @@ class FleetCoalescer:
                 for slot, ep in enumerate(self.members):
                     if slot in skip:
                         continue
-                    batch = ep.batcher.collect_nowait(rnd.id)
+                    batch = ep.batcher.gather(rnd.id)
                     if not batch:
                         continue
                     rows = sum(r.x.shape[0] for r in batch)
@@ -260,7 +227,9 @@ class FleetCoalescer:
         t0 = self._clock()
         riders: List[tuple] = []
         with spans.span("repro.fleet.assemble"):
-            buf = self._staging_buffer(bucket)
+            buf = self._staging_buffer(
+                (self.stack.n_models, bucket, self.stack.n_features),
+                np.float32)
             for slot, ep, batch, rows in stacked:
                 try:
                     off = 0
@@ -296,62 +265,41 @@ class FleetCoalescer:
         # Pipeline depth 1: hand the new round to the device FIRST, then
         # finalize the previous one (unless the hold already did) — round
         # t's materialization wait runs while round t+1 computes.
-        prev, self._pending = self._pending, (out, riders, bucket, t1,
-                                              rnd.id)
-        if prev is not None:
-            self._finalize_round(prev)
+        self._finalize_pending(launched=Launch(riders, bucket, buf, t1,
+                                               out=out, taker=rnd.id))
         return True
 
-    def _finalize_pending(self, in_hold: bool = False) -> bool:
-        """Finalize the round in flight, if any; True if there was one."""
-        prev, self._pending = self._pending, None
-        if prev is not None:
-            self._finalize_round(prev, in_hold)
-        return prev is not None
-
-    def _finalize_round(self, pending: _Pending,
-                        in_hold: bool = False) -> None:
-        """Materialize a launched round and scatter results to futures.
-        Every rider's future resolves by the time this returns.
+    def _finalize_pending(self, in_hold: bool = False,
+                          launched: Optional[Launch] = None) -> bool:
+        """Force the round in flight, if any, and scatter each rider's
+        outputs to its futures; ``launched`` takes its place.  True if
+        there was one, and then every rider's future has resolved.
         ``in_hold``: run inside the next round's straggler hold."""
-        out, riders, bucket, t_launch, launched_by = pending
-        with spans.span("repro.fleet.finalize", round=launched_by,
+        fl, self._inflight = self._inflight, launched
+        if fl is None:
+            return False
+        with spans.span("repro.fleet.finalize", round=fl.taker,
                         in_hold=int(in_hold)):
             try:
-                # Forces the device computation.
-                with spans.span("repro.predict.sync", rows=out.size):
-                    y = np.asarray(out, np.int32)
+                y = self._force(fl, self.stack.n_models * fl.bucket)
             except Exception:
                 # Deferred device failure: the whole round recomputes on
                 # the members' own paths (retry/bisection semantics
                 # included).
                 self.n_stack_fallbacks += 1
-                for _, ep, batch, _ in riders:
+                for _, ep, batch, _ in fl.batch:
                     self._serve_solo(ep, batch)
-                return
-            self.device_s += self._clock() - t_launch
+                return True
             done = self._clock()
-            for slot, ep, batch, rows in riders:
-                meta = {"coalesced": True, "degraded": False,
-                        "number_format": ep.artifact.target.number_format}
-                try:
-                    ep.stats.record_batch(len(batch), rows, bucket,
-                                          [done - r.t_enqueue for r in batch],
-                                          meta=meta)
-                except Exception:
-                    pass  # a stats sink must never take down serving
+            for slot, ep, batch, rows in fl.batch:
                 if ep.breaker is not None:
                     ep.breaker.record_success()
                 self.n_stacked_requests += len(batch)
-                row, off = y[slot], 0
-                for r in batch:
-                    n = r.x.shape[0]
-                    r.future.batch_meta = meta
-                    try:
-                        r.future.set_result(row[off:off + n])
-                    except BaseException:
-                        pass  # cancelled/raced future; keep scattering
-                    off += n
+                meta = {"coalesced": True, "degraded": False,
+                        "number_format": ep.artifact.target.number_format}
+                self._scatter(batch, y[slot], rows, fl.bucket, meta, done,
+                              ep.stats.record_batch)
+        return True
 
     def _run(self) -> None:
         while True:
@@ -363,5 +311,5 @@ class FleetCoalescer:
             except BaseException:  # pragma: no cover - belt and braces
                 moved = False
             if not moved:
-                self._event.wait(self._idle_wait_s)
+                self._event.wait(_IDLE_WAIT_S)
                 self._event.clear()

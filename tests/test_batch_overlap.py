@@ -326,7 +326,7 @@ def test_detach_with_a_batch_in_flight_resolves_every_future():
         assert probe.at_call == [0, 1] and probe.unforced == 0
         # The caller serves the rest itself, blocking.
         while True:
-            batch = b.collect_nowait()
+            batch = b.gather()
             if not batch:
                 break
             b.serve(batch)

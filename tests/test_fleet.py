@@ -541,10 +541,41 @@ def test_get_or_stack_dedupes(cache, fleet_arts):
     assert s1 is s2
 
 
-def test_register_pretune_warms_ladder(cache, fleet_arts, blobs):
+@pytest.mark.parametrize("where", ["register", "fleet"])
+def test_register_pretune_warms_ladder(cache, fleet_arts, blobs, where,
+                                       monkeypatch):
     """pretune=<example> walks the bucket ladder at registration — the
-    launcher's --pretune path — and serving stays golden."""
+    launcher's --pretune path — and serving stays golden.  A fleet warms
+    every member's solo ladder through ``MicroBatcher.warm`` on its own
+    thread before its first stacked round."""
     xte = blobs[2]
+    if where == "fleet":
+        warmed, real = [], MicroBatcher.warm
+
+        def warm(self, example):
+            warmed.append((self.name, example.shape,
+                           threading.current_thread().name))
+            return real(self, example)
+
+        monkeypatch.setattr(MicroBatcher, "warm", warm)
+        svc = _fleet_service(cache, fleet_arts)
+        co = next(iter(svc._fleets.values()))
+        try:
+            for i in range(50):
+                futs = [svc.endpoint(f"m{e}").submit(xte[i:i + 1])
+                        for e in range(E)]
+                for e, f in enumerate(futs):
+                    assert f.result(timeout=120)[0] == \
+                        fleet_arts[e].predict(xte[i:i + 1])[0]
+                if co.n_stacked_dispatches:
+                    break
+            assert co.n_stacked_dispatches >= 1
+        finally:
+            svc.close()
+        assert {(name, shape, thread) for name, shape, thread in warmed} \
+            == {(f"m{e}", (1, F), "fleet-coalescer") for e in range(E)}
+        assert co.snapshot()["warmup_failures"] == 0
+        return
     svc = InferenceService(cache=cache)
     try:
         ep = svc.register("warm", artifact=fleet_arts[0], policy=_policy(),

@@ -179,8 +179,7 @@ def test_bisection_isolates_single_poison_in_olog_dispatches(n):
             raise RuntimeError("poison row")
         return x[:, 0] * 2
 
-    mb = MicroBatcher(predict, BatchingPolicy(max_batch=n, warmup=False,
-                                              bucketing="exact"))
+    mb = MicroBatcher(predict, BatchingPolicy(max_batch=n, warmup=False))
     try:
         poison_slot = n // 3
         reqs = [_Request(np.full((1, 2), POISON if i == poison_slot else i,

@@ -24,6 +24,7 @@ from repro.models import (train_decision_tree, train_kernel_svm,
                           train_linear_svm, train_logistic, train_mlp)
 from repro.serve import (ArtifactCache, BatchingPolicy, InferenceService,
                          MicroBatcher, ModelRouter)
+from repro.serve.reliability import DeadlineExceeded
 
 KINDS = ("tree", "logistic", "mlp", "svm-linear", "svm-poly", "svm-rbf")
 
@@ -73,23 +74,6 @@ def test_policy_bucket_ladder():
     # non-power-of-two cap becomes the top bucket
     assert BatchingPolicy(max_batch=48).buckets() == (1, 2, 4, 8, 16, 32, 48)
     assert BatchingPolicy(max_batch=48).bucket_for(40) == 48
-    assert BatchingPolicy(max_batch=8, bucketing="exact").buckets() == (8,)
-    # exact mode never pads: the bucket is the batch itself
-    assert BatchingPolicy(max_batch=64, bucketing="exact").bucket_for(5) == 5
-
-
-def test_exact_bucketing_does_not_pad(blobs_module):
-    _, _, xte, _, _ = blobs_module
-    calls = []
-
-    def predict(x):
-        calls.append(x.shape[0])
-        return np.zeros(x.shape[0], np.int32)
-
-    with MicroBatcher(predict, BatchingPolicy(max_batch=64, bucketing="exact",
-                                              warmup=False)) as mb:
-        mb.submit(xte[:5]).result(timeout=60)
-    assert calls == [5]
 
 
 def test_policy_validation_and_clamp():
@@ -97,8 +81,6 @@ def test_policy_validation_and_clamp():
         BatchingPolicy(max_batch=0)
     with pytest.raises(ValueError):
         BatchingPolicy(max_wait_ms=-1)
-    with pytest.raises(ValueError):
-        BatchingPolicy(bucketing="mod3")
     assert BatchingPolicy(max_batch=64).clamped(16).max_batch == 16
     assert BatchingPolicy(max_batch=8).clamped(None).max_batch == 8
     assert BatchingPolicy(max_batch=8).clamped(16).max_batch == 8
@@ -107,6 +89,47 @@ def test_policy_validation_and_clamp():
 # ---------------------------------------------------------------------------
 # MicroBatcher
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("waiting", [True, False],
+                         ids=["worker", "coalescer"])
+def test_gather_applies_one_set_of_rules_for_both_callers(waiting):
+    """The worker's gather (behind the request it blocked for, waiting up
+    to its ``max_wait_ms``) and the coalescer's (no wait) form the same
+    batch: an expired request is resolved and skipped, the request that
+    would overflow ``max_batch`` heads the next batch, and a close
+    sentinel stays queued for the final drain."""
+    mb = MicroBatcher(lambda x: x[:, 0], BatchingPolicy(
+        max_batch=4, max_wait_ms=60_000, eager_when_idle=False,
+        warmup=False))
+    mb.detach_worker()
+    rows = [np.full((n, 2), i, np.float32) for i, n in enumerate((1, 1, 2, 2))]
+    head, expired, mate, over = (
+        mb.submit(x, timeout_s=0.0 if i == 1 else None)
+        for i, x in enumerate(rows))
+    mb._queue.put(None)  # what close() leaves behind its last request
+
+    def gather(taker):
+        # The worker's gather starts behind the live request it blocked
+        # for; the coalescer's takes its first request itself.
+        if not waiting:
+            return mb.gather(taker)
+        first = mb._carry or mb._queue.get_nowait()
+        mb._carry = None
+        return mb.gather(taker, first)
+
+    batch = gather(1)
+    assert [r.future for r in batch] == [head, mate]
+    with pytest.raises(DeadlineExceeded):
+        expired.result(timeout=0)
+    assert mb._carry.future is over
+    assert mb.n_expired == 1
+    batch = gather(2)
+    assert [r.future for r in batch] == [over] and mb._carry is None
+    assert mb._queue.get_nowait() is None and mb._queue.empty()
+    mb.serve(batch)
+    np.testing.assert_array_equal(over.result(timeout=0), [3, 3])
+    mb.close()
+
+
 def test_microbatcher_matches_direct_predict(artifacts, blobs_module):
     _, _, xte, _, _ = blobs_module
     art = artifacts["tree"]

@@ -73,11 +73,42 @@ def _subtract_phantom_rows(stats: FxpStats, k: int, pad_row_cache: list,
                     np.asarray(stats.total) - k * per.total)
 
 
+def _flat_jit(predict: Callable) -> Callable:
+    """``jax.jit(predict)`` whose input crosses to the device as one flat
+    buffer.
+
+    A TPU keeps a 2-D 32-bit array in (8, 128) tiles, and lays a ``(rows,
+    features)`` program input out transposed, so the runtime transposes
+    each host array into tiles on the host; a 1-D array's tiles are runs of
+    its memory, so it crosses as a straight copy.  The program takes the
+    rows flat with their shape as a static argument and restores it with a
+    reshape on the device, then runs ``predict`` unchanged: the same
+    arithmetic, outputs and stats.  The jit cache is keyed by the shape, so
+    it holds one executable per bucket, as a 2-D jit would.
+
+    Host input (anything ``np.asarray`` takes) is passed as
+    ``x.reshape(-1)``: a view of any contiguous array, a copy of any other.
+    A ``jax.Array`` is reshaped where it lives, eagerly, outside the
+    program: one more dispatch and a 2-D -> 1-D relayout on the device,
+    which the program then undoes.  The serving paths hand it host arrays.
+    """
+    program = jax.jit(lambda flat, shape: predict(flat.reshape(shape)),
+                      static_argnums=1)
+
+    def run(x):
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        return program(x.reshape(-1), x.shape)
+
+    return run
+
+
 def _specialize(program: Lowered, target: Target, kind: str = "") -> Callable:
     """Stage 4: backend jit + batch policy.
 
     * ``ref`` runs the program eagerly (op-by-op oracle semantics, easiest to
-      debug); ``xla``/``pallas`` wrap the whole program in ``jax.jit``.
+      debug); ``xla``/``pallas`` wrap the whole program in ``jax.jit``, its
+      input taken flat.
     * ``emit`` serves through the generated C: the lowering's ``emit_spec``
       is templated into a freestanding translation unit, built once with the
       system ``cc`` on first predict (lazy — emission itself needs no
@@ -90,7 +121,7 @@ def _specialize(program: Lowered, target: Target, kind: str = "") -> Callable:
     """
     predict = program.predict
     if target.backend in ("xla", "pallas") and program.jittable:
-        predict = jax.jit(predict)
+        predict = _flat_jit(predict)
     elif target.backend == "emit":
         from repro import emit as emit_mod
 

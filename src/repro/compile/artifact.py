@@ -136,6 +136,16 @@ class CompiledArtifact:
         return None if self.quant_plan is None else self.quant_plan.descriptor()
 
     @property
+    def flat_input(self) -> bool:
+        """Whether ``_predict`` sends its input to the device as one flat
+        buffer (repro.compile.api._flat_jit): the jitted single-device
+        program and a ``fused`` mesh over it; not ``ref``, ``emit`` or an
+        ``spmd`` mesh, which builds its own jit."""
+        return (self.target.backend in ("xla", "pallas")
+                and self._program is not None and self._program.jittable
+                and self.mesh_strategy in (None, "fused"))
+
+    @property
     def kernel_strategy(self) -> Optional[str]:
         """How the pallas backend dispatched this model's forward pass:
         ``"megakernel"`` (the whole model in one ``pallas_call``),
@@ -179,7 +189,8 @@ class CompiledArtifact:
         """One dispatch; returns the outputs unforced: a device array that
         JAX may still be computing (or a host array, where the program
         makes one).  The caller forces it with ``np.asarray``."""
-        with spans.span("repro.predict.call", rows=len(x)):
+        with spans.span("repro.predict.call", rows=len(x),
+                        flat=int(self.flat_input)):
             out, _ = self._predict(x)
         return out
 

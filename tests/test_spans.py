@@ -8,6 +8,7 @@
   batch or round that took it; the profiler's own trace holds the same
   scoped spans; spans keep the real clock under an injected batcher clock;
   a calibrated rbf SVM endpoint's dispatch records the same program spans;
+  a program call says whether its input crossed to the device flat;
 * the ring keeps at most ``CAPACITY`` records and counts what it drops.
 """
 
@@ -337,6 +338,32 @@ def test_svm_endpoint_dispatch_records_the_program_spans(rows, tmp_path):
                   "repro.batch")] >= 2
     assert chain[("repro.predict.sync", "repro.batch.dispatch",
                   "repro.batch")] >= 2
+
+
+def test_program_call_says_whether_its_input_crossed_flat(rows, tmp_path):
+    """A pallas solo endpoint's program takes its rows as one flat buffer,
+    so its ``repro.predict.call`` carries ``flat`` 1; a ``ref`` artifact
+    runs eagerly on the rows as given, ``flat`` 0."""
+    y = np.arange(len(rows), dtype=np.int32) % C
+    model = train_mlp(rows, y, C, hidden=(8,), epochs=2, seed=0)
+    svc = InferenceService()
+    try:
+        for backend in ("pallas", "ref"):
+            svc.register(backend, model, Target(number_format="fxp16",
+                                                backend=backend),
+                         policy=BatchingPolicy(max_batch=4, max_wait_ms=2))
+            svc.predict(backend, rows[:4])  # warm the buckets
+        spans.clear()
+        with jax.profiler.trace(str(tmp_path)):
+            for backend in ("pallas", "ref"):
+                svc.submit(backend, rows[0]).result(timeout=120)
+        recs = spans.collected(float("-inf"), float("inf"))
+    finally:
+        svc.close()
+    calls = sorted((r for r in recs if r[0] == "repro.predict.call"),
+                   key=lambda r: r[1])
+    assert [_ints(r) for r in calls] == [{"rows": 1, "flat": 1},
+                                         {"rows": 1, "flat": 0}]
 
 
 def test_spans_keep_the_real_clock_under_an_injected_one(tmp_path):
